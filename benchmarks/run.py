@@ -83,6 +83,9 @@ def select_modules(only: Optional[str]) -> List[str]:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     try:
         only = flag_value(sys.argv, "--only")
         json_path = flag_value(sys.argv, "--json") or os.environ.get("BENCH_JSON")

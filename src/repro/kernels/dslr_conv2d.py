@@ -60,6 +60,18 @@ from repro.core import dslr as core_dslr
 
 from . import tuning
 
+# The digit scales enter whole, in SMEM, and the kernel reads the current
+# plane's weight as a scalar.  A per-step (1, 1) VMEM block of the (D,) array
+# would be refused by Mosaic: a block's second-to-last dimension must be a
+# multiple of 8 or the whole array's.
+SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+# Planes are exact in any float format, but the weights are f32: at XLA's
+# default TPU precision the MXU would round them to bf16.  HIGHEST keeps every
+# plane x weight product exact, the premise of the bounds in docs/NUMERICS.md;
+# kernels/ref.py names the same precision.
+DOT_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _epilogue(acc, bias_ref, apply_relu: bool):
     """Fused flush epilogue: bias add + ReLU ride the last digit step, so a
@@ -105,7 +117,7 @@ def _emit_packed_planes(res, inv_ref, out_ref, frac_bits: int, n_digits: int):
 def _dslr_conv2d_kernel(
     planes_ref,  # (1, bm, T) int8 — digit plane d of the im2col patches
     w_ref,  # (T, bn) f32 — stationary flattened filter tile
-    scale_ref,  # (1, 1) f32 — 2**-d digit weight of this plane
+    scale_ref,  # SMEM (D,) f32 — digit weights, plane d reads scale_ref[d]
     *refs,  # [row_scale_ref (bm, 1) if has_row_scale,] [bias_ref (1, bn) if
     #        has_bias,] out_ref (bm, bn), acc_ref scratch
     n_digits: int,
@@ -130,7 +142,7 @@ def _dslr_conv2d_kernel(
     # sample's scale, broadcast (bm, 1) x (bm, bn)) — so the flush step is a
     # pure add/max epilogue in both cases and holds real conv values when
     # the bias lands
-    scale = scale_ref[0, 0]
+    scale = scale_ref[d]
     if has_row_scale:
         scale = scale * row_scale_ref[...]
 
@@ -139,12 +151,15 @@ def _dslr_conv2d_kernel(
             plane.astype(jnp.float32),
             w_ref[...],
             (((1,), (0,)), ((), ())),
+            precision=DOT_PRECISION,
             preferred_element_type=jnp.float32,
         )
         acc_ref[...] += scale * contrib
 
     if skip_zero_planes:
-        jax.lax.cond(jnp.any(plane != 0), _accumulate, lambda: None)
+        # widened first: Mosaic cannot relayout an int8-shaped i1 mask
+        live = jnp.any(plane.astype(jnp.int32) != 0)
+        jax.lax.cond(live, _accumulate, lambda: None)
     else:
         _accumulate()
 
@@ -196,9 +211,9 @@ def dslr_conv2d_planes_mxu(
     in_specs = [
         pl.BlockSpec((1, bm, T), lambda m, n, d: (d, m, 0)),
         pl.BlockSpec((T, bn), lambda m, n, d: (0, n)),
-        pl.BlockSpec((1, 1), lambda m, n, d: (d, 0)),
+        SMEM_WHOLE,
     ]
-    operands = [planes, wf, digit_scales.reshape(D, 1).astype(jnp.float32)]
+    operands = [planes, wf, digit_scales.astype(jnp.float32)]
     if has_row_scale:
         rs = row_scale.astype(jnp.float32).reshape(M, 1)
         if Mp != M:
@@ -267,7 +282,7 @@ def _dslr_conv2d_packed_kernel(
     fetch_ref,  # SMEM (Mt, D) int32 — resident byte group per step (index map)
     packed_ref,  # (1, bm, T) int8 — byte group fetch[m, d] of the patches
     w_ref,  # (T, bn) f32 — stationary flattened filter tile
-    scale_ref,  # (1, 1) f32 — 2**-d digit weight of this plane
+    scale_ref,  # SMEM (D,) f32 — digit weights, plane d reads scale_ref[d]
     *refs,  # [row_scale_ref,] [bias_ref,] [inv_ref if emit,] out_ref, acc_ref
     n_digits: int,
     skip_zero_planes: bool,
@@ -287,7 +302,7 @@ def _dslr_conv2d_packed_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    scale = scale_ref[0, 0]
+    scale = scale_ref[d]
     if has_row_scale:
         scale = scale * row_scale_ref[...]
 
@@ -301,6 +316,7 @@ def _dslr_conv2d_packed_kernel(
             plane,
             w_ref[...],
             (((1,), (0,)), ((), ())),
+            precision=DOT_PRECISION,
             preferred_element_type=jnp.float32,
         )
         acc_ref[...] += scale * contrib
@@ -410,9 +426,9 @@ def dslr_conv2d_planes_packed_mxu(
     in_specs = [
         pl.BlockSpec((1, bm, T), lambda m, n, d, act, fetch: (fetch[m, d], m, 0)),
         pl.BlockSpec((T, bn), lambda m, n, d, act, fetch: (0, n)),
-        pl.BlockSpec((1, 1), lambda m, n, d, act, fetch: (d, 0)),
+        SMEM_WHOLE,
     ]
-    operands = [packed, wf, digit_scales.reshape(D, 1).astype(jnp.float32)]
+    operands = [packed, wf, digit_scales.astype(jnp.float32)]
     if has_row_scale:
         rs = row_scale.astype(jnp.float32).reshape(M, 1)
         if Mp != M:

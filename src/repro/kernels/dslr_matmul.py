@@ -39,13 +39,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import digits as dig
 
 from . import tuning
-from .dslr_conv2d import plane_fetch_indices
+from .dslr_conv2d import DOT_PRECISION, SMEM_WHOLE, plane_fetch_indices
 
 
 def _dslr_matmul_kernel(
     planes_ref,  # (1, bm, K) int8 — digit plane d for this m-tile
     w_ref,  # (K, bn) f32 — stationary weight tile
-    scale_ref,  # (1, 1) f32 — 2**-d digit weight for this plane
+    scale_ref,  # SMEM (D,) f32 — digit weights, plane d reads scale_ref[d]
     out_ref,  # (bm, bn) f32
     acc_ref,  # VMEM scratch (bm, bn) f32
     *,
@@ -59,13 +59,14 @@ def _dslr_matmul_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     plane = planes_ref[0]
-    scale = scale_ref[0, 0]
+    scale = scale_ref[d]
 
     def _accumulate():
         contrib = jax.lax.dot_general(
             plane.astype(jnp.float32),
             w_ref[...],
             (((1,), (0,)), ((), ())),
+            precision=DOT_PRECISION,
             preferred_element_type=jnp.float32,
         )
         acc_ref[...] += scale * contrib
@@ -73,7 +74,9 @@ def _dslr_matmul_kernel(
     if skip_zero_planes:
         # CSD leaves ~2/3 of digits zero — skip the MXU pass for all-zero
         # plane tiles (the paper's reduced-activity argument, in tile form).
-        jax.lax.cond(jnp.any(plane != 0), _accumulate, lambda: None)
+        # widened first: Mosaic cannot relayout an int8-shaped i1 mask
+        live = jnp.any(plane.astype(jnp.int32) != 0)
+        jax.lax.cond(live, _accumulate, lambda: None)
     else:
         _accumulate()
 
@@ -116,13 +119,13 @@ def dslr_matmul_planes(
         in_specs=[
             pl.BlockSpec((1, bm, K), lambda m, n, d: (d, m, 0)),
             pl.BlockSpec((K, bn), lambda m, n, d: (0, n)),
-            pl.BlockSpec((1, 1), lambda m, n, d: (d, 0)),
+            SMEM_WHOLE,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, d: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(planes, w.astype(jnp.float32), digit_scales.reshape(D, 1).astype(jnp.float32))
+    )(planes, w.astype(jnp.float32), digit_scales.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -139,7 +142,7 @@ def _dslr_matmul_packed_kernel(
     fetch_ref,  # SMEM (Mt, D) int32 — resident byte group per step (index map)
     packed_ref,  # (1, bm, K) int8 — byte group fetch[m, d] of the activations
     w_ref,  # (K, bn) f32 — stationary projection weight tile
-    scale_ref,  # (1, 1) f32 — 2**-d digit weight of this plane (scale-folded)
+    scale_ref,  # SMEM (D,) f32 — digit weights (scale-folded), read at d
     *refs,  # [row_scale_ref (bm, 1),] [bias_ref (1, bn),] out_ref, acc_ref
     n_digits: int,
     skip_zero_planes: bool,
@@ -160,7 +163,7 @@ def _dslr_matmul_packed_kernel(
     # per-plane step — folded into ``digit_scales`` (per-tensor) or via
     # ``row_scale`` (per-token: each output row carries its own token's
     # scale) — so the flush step is a pure bias add on real projection values
-    scale = scale_ref[0, 0]
+    scale = scale_ref[d]
     if has_row_scale:
         scale = scale * row_scale_ref[...]
 
@@ -174,6 +177,7 @@ def _dslr_matmul_packed_kernel(
             plane,
             w_ref[...],
             (((1,), (0,)), ((), ())),
+            precision=DOT_PRECISION,
             preferred_element_type=jnp.float32,
         )
         acc_ref[...] += scale * contrib
@@ -257,9 +261,9 @@ def dslr_matmul_planes_packed(
     in_specs = [
         pl.BlockSpec((1, bm, K), lambda m, n, d, act, fetch: (fetch[m, d], m, 0)),
         pl.BlockSpec((K, bn), lambda m, n, d, act, fetch: (0, n)),
-        pl.BlockSpec((1, 1), lambda m, n, d, act, fetch: (d, 0)),
+        SMEM_WHOLE,
     ]
-    operands = [packed, wf, digit_scales.reshape(D, 1).astype(jnp.float32)]
+    operands = [packed, wf, digit_scales.astype(jnp.float32)]
     if has_row_scale:
         rs = row_scale.astype(jnp.float32).reshape(M, 1)
         if Mp != M:

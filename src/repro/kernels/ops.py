@@ -181,9 +181,8 @@ def dslr_conv2d_planes(
     — packing is a bijection and the kernel's f32 accumulation sequence is
     unchanged.
 
-    ``block_m``/``block_n`` default to the autotuner's choice for this
-    geometry (``kernels/tuning.py``: cached per-(geometry, backend) table,
-    measured sweep on real backends); pass explicit ints to pin them.
+    ``block_m``/``block_n`` default to the autotuner's table entry for this
+    geometry (``kernels/tuning.py``); pass explicit ints to pin them.
     """
     return dslr_conv2d_planes_flat(
         x,

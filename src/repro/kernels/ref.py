@@ -1,11 +1,23 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose targets).
+
+Every matmul names the kernels' dot precision (``DOT_PRECISION``): on a TPU
+the default f32 precision rounds operands to bf16, and oracle and kernel
+would then disagree by far more than summation order.
+"""
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import digits as dig
 from repro.core import dslr as core_dslr
+
+from .dslr_conv2d import DOT_PRECISION
+
+_matmul = functools.partial(jnp.matmul, precision=DOT_PRECISION)
+_einsum = functools.partial(jnp.einsum, precision=DOT_PRECISION)
 
 
 def dslr_conv2d_planes_ref(
@@ -64,7 +76,7 @@ def dslr_conv2d_planes_ref(
         s, plane = jp
         if row_scale is not None:
             s = s * row_scale
-        return acc + s * (plane.astype(jnp.float32) @ w_flat), None
+        return acc + s * _matmul(plane.astype(jnp.float32), w_flat), None
 
     zeros = jnp.zeros((B * Ho * Wo, w_flat.shape[1]), jnp.float32)
     acc, _ = jax.lax.scan(body, zeros, (scales, planes))
@@ -107,7 +119,7 @@ def planes_scan_flat_ref(
         s, plane = jp
         if rs is not None:
             s = s * rs
-        return acc + s * (plane.astype(jnp.float32) @ w32), None
+        return acc + s * _matmul(plane.astype(jnp.float32), w32), None
 
     zeros = jnp.zeros((planes.shape[1], w32.shape[1]), jnp.float32)
     acc, _ = jax.lax.scan(
@@ -124,10 +136,12 @@ def dslr_matmul_planes_ref(
     planes: jax.Array, w: jax.Array, digit_scales: jax.Array
 ) -> jax.Array:
     """sum_d scales[d] * (planes[d] @ w) — dense, no skipping."""
-    contribs = jnp.einsum(
+    contribs = _einsum(
         "dmk,kn->dmn", planes.astype(jnp.float32), w.astype(jnp.float32)
     )
-    return jnp.tensordot(digit_scales.astype(jnp.float32), contribs, axes=1)
+    return jnp.tensordot(
+        digit_scales.astype(jnp.float32), contribs, axes=1, precision=DOT_PRECISION
+    )
 
 
 def dslr_matmul_packed_ref(
@@ -166,7 +180,7 @@ def dslr_matmul_packed_ref(
         s, plane = jp
         if row_scale is not None:
             s = s * row_scale
-        return acc + s * (plane.astype(jnp.float32) @ wf), None
+        return acc + s * _matmul(plane.astype(jnp.float32), wf), None
 
     zeros = jnp.zeros((x.shape[0], w.shape[1]), jnp.float32)
     acc, _ = jax.lax.scan(body, zeros, (scales, planes))
@@ -209,7 +223,7 @@ def slstm_sweep_ref(wx: jax.Array, r_w: jax.Array, n_heads: int):
 
     def step(state, g_in):
         c, n, h, m = state
-        rec = jnp.einsum(
+        rec = _einsum(
             "bhd,hde->bhe", h.reshape(B, n_heads, Dh), r_w.astype(jnp.float32)
         ).reshape(B, 4 * d)
         g = g_in.astype(jnp.float32) + rec

@@ -15,7 +15,9 @@ paper's roofline quantities measurable in-repo without a hardware profiler:
 
 The model is exact for the interpret-mode kernels (one buffer per block, no
 double buffering) and an upper bound for Mosaic (which may add prefetch
-overlap but never *more* copies of the same blocks).
+overlap but never *more* copies of the same blocks).  The digit scales are
+not a blocked operand: they enter whole in SMEM once per launch
+(``D * 4`` bytes), so no index map of theirs is replayed.
 """
 from __future__ import annotations
 

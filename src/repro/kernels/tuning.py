@@ -15,26 +15,27 @@ relaxed to 8 in interpret mode, where tiny test shapes would otherwise pad
 
 Autotuner
 ---------
-``autotune_conv_blocks`` replaces the hardcoded 128/128 default of the conv
-path: given the digit-plane matmul geometry (M, N, T, digits) it returns a
-``(block_m, block_n)`` pair from a cached per-(geometry, backend) table.  On
-a cache miss with a real (non-interpret) backend it runs a measured sweep —
-each candidate block shape executes the actual packed conv kernel on
-synthetic CSD-sparse planes and the fastest wins.  In interpret mode (the
-CPU CI) wall-clock is Python-interpreter noise, so the miss path records the
-MXU-aligned heuristic instead of timing; pass ``measure=True`` to force the
-sweep anywhere (exercised by the unit tests).  The table is process-global:
-an engine's first forward pays the sweep once per conv geometry, every
-subsequent trace hits the cache.
+``autotune_conv_blocks`` gives the conv and matmul paths their
+``(block_m, block_n)`` pair from a cached per-(geometry, backend) table.  A
+cache miss records the MXU-aligned 128/128 heuristic.  ``measure=True`` runs
+a measured sweep instead: each candidate block shape executes the actual
+packed conv kernel on synthetic CSD-sparse planes and the fastest wins.  The
+sweep is never the default because the engines consult the table while
+``jax.jit`` traces them: there the candidate calls return tracers,
+``block_until_ready`` returns at once, and the sweep would time tracing, not
+kernels.  The table is process-global.
 """
 from __future__ import annotations
 
 import time
-from typing import Dict, Iterable, NamedTuple, Optional, Tuple
+from typing import Dict, Iterable, NamedTuple, Tuple
 
 import jax
 
-SUBLANE = 8  # f32 sublane multiple; int8 planes ride an 8-row tile too
+# f32 sublane multiple.  An int8 plane block is then either the whole padded
+# row extent (M <= block_m) or a multiple of 32 rows, both of which Mosaic
+# accepts (tests/test_tpu_compile.py compiles M = 8, 49 and 12544).
+SUBLANE = 8
 LANE = 128  # MXU/VPU lane width
 
 
@@ -114,25 +115,23 @@ def autotune_conv_blocks(
     n_digits: int,
     packed: bool = True,
     interpret: bool = False,
-    measure: Optional[bool] = None,
+    measure: bool = False,
     candidates: Iterable[Tuple[int, int]] = DEFAULT_CANDIDATES,
 ) -> Tuple[int, int]:
     """Preferred (block_m, block_n) for a digit-plane conv matmul of geometry
     ``planes (D, M, T) @ w (T, N)``.
 
-    Consults the process-global table first; on a miss either measures (real
-    backends, or ``measure=True``) or records the 128/128 MXU heuristic
-    (interpret mode, ``measure=False``).  The returned pair is a *preferred*
-    shape — ``conv_tile_dims`` still clamps it to the padded problem size at
-    the kernel call.
+    Consults the process-global table first; on a miss records the 128/128
+    MXU heuristic, or with ``measure=True`` times the candidates (call it
+    outside any trace).  The returned pair is a *preferred* shape —
+    ``conv_tile_dims`` still clamps it to the padded problem size at the
+    kernel call.
     """
     backend = jax.default_backend()
     key = ("conv_planes", M, N, T, n_digits, bool(packed), backend, bool(interpret))
     hit = _BLOCK_TABLE.get(key)
     if hit is not None:
         return hit
-    if measure is None:
-        measure = not interpret and backend != "cpu"
     if not measure:
         best = (128, 128)
         _BLOCK_TABLE[key] = best

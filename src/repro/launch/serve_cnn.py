@@ -35,6 +35,9 @@ degradation is on by default — overload steps tiers down a digit-prefix
 ladder instead of shedding (``--no-brownout`` restores plain shedding,
 ``--brownout-floor`` sets the smallest prefix served) — and degraded
 requests are reported with their ``digits_spent`` and sound error bound.
+
+Exit status: nonzero when a request failed or the guardrail rerouted a wave
+to the jnp oracle, unless ``--chaos`` injected the faults.
 """
 from __future__ import annotations
 
@@ -45,6 +48,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import common as cm
 from repro.models.engine import compile_cnn
 from repro.models.graph import CnnConfig, ExecutionPolicy, build_graph, graph_spec
@@ -110,6 +114,7 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 def main() -> None:
     args = parse_args()
+    use_compile_cache()
     planning = args.plan_latency is not None or args.plan_error is not None
     custom = planning or bool(args.per_layer_budgets) or args.budget is not None
 
@@ -210,10 +215,11 @@ def main() -> None:
     completed = [h for h in handles if h.done() and h._error is None]
     failed = [h for h in handles if h._error is not None]
     lat_ms = np.array([(h.done_time - h.submit_time) * 1e3 for h in handles])
-    n_dev = len(jax.devices())
+    dev = jax.devices()[0]
     print(
         f"[serve_cnn] {args.net} width={args.width} requests={args.requests} "
-        f"qps={args.qps or 'closed-loop'} buckets={buckets} on {n_dev} device(s): "
+        f"qps={args.qps or 'closed-loop'} buckets={buckets} on "
+        f"{len(jax.devices())} {dev.platform} device(s) ({dev.device_kind}): "
         f"build {build_ms:.1f} ms, warmup {warmed} programs {warm_ms:.1f} ms, "
         f"p50 {np.percentile(lat_ms, 50):.1f} ms p99 {np.percentile(lat_ms, 99):.1f} ms, "
         f"throughput {len(handles) / max(total_s, 1e-9):.1f} img/s, shed {shed}",
@@ -264,6 +270,13 @@ def main() -> None:
         print(f"[serve_cnn] adaptive: {len(decided)} request(s), digit planes "
               f"spent mean {spent.mean():.1f} min {spent.min()} max {spent.max()}; "
               f"decided at {dist}")
+    # without --chaos nothing was injected: a failed request or a wave the
+    # guardrail rerouted to the jnp oracle is a fault of the served path
+    if injector is None and (failed or server.stats["oracle_waves"]):
+        raise SystemExit(
+            f"[serve_cnn] FAILED: {len(failed)} failed request(s), "
+            f"{server.stats['oracle_waves']} wave(s) rerouted to the oracle"
+        )
 
 
 if __name__ == "__main__":

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.lm import DslrLmServer, compile_lm
 from repro.models import common as cm
 from repro.models import transformer as tf
@@ -86,6 +87,7 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 def main() -> None:
     args = parse_args()
+    use_compile_cache()
     planning = args.plan_latency is not None or args.plan_error is not None
     custom = planning or args.budget is not None
 

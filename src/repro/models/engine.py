@@ -361,6 +361,14 @@ class DslrEngine:
             self.graph, self.policy, self._exec_params, self._exec_weights, x
         )
 
+    def lower(self, x: jax.Array) -> jax.stages.Lowered:
+        """The lowered program ``self(x)`` runs; ``.compile()`` on it is
+        served from the jit cache once ``self(x)`` has run, so inspecting
+        the compiled HLO costs no second compile."""
+        return _jit_execute.lower(
+            self.graph, self.policy, self._exec_params, self._exec_weights, x
+        )
+
     def with_policy(self, policy: ExecutionPolicy) -> "DslrEngine":
         """Derived engine under a different policy, sharing this engine's
         already-flattened stationary weights (re-flattens nothing) — how the
@@ -399,7 +407,13 @@ class DslrEngine:
                 from repro.launch import mesh as mesh_lib
 
                 devs = jax.devices()
-                mesh = jax.make_mesh((len(devs), 1), ("data", "model"))
+                # Auto axes: the program's reshapes (im2col) carry no
+                # sharding rules of their own, which Explicit axes demand
+                mesh = jax.make_mesh(
+                    (len(devs), 1),
+                    ("data", "model"),
+                    axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                )
                 batch_axis = mesh_lib.rules_for(mesh)["batch"]
                 self._serve_sharding = (
                     len(devs), NamedSharding(mesh, P(batch_axis))

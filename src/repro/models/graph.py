@@ -159,7 +159,7 @@ class ExecutionPolicy:
     boundary (4 MSDF digits per int8 byte, bitmap-driven dead-plane skip) —
     bitwise identical to unpacked execution, ~4x less traffic on the
     dominant operand.  ``block_m``/``block_n`` of ``None`` (the default)
-    defer to the measured block-shape autotuner (``kernels/tuning.py``);
+    defer to the block-shape table (``kernels/tuning.py``);
     explicit ints pin the tile shape.
     """
 

@@ -1039,7 +1039,8 @@ class DslrServer:
         """Trace/compile every (bucket, SLO policy) program up front with
         zero images so steady-state latency percentiles exclude jit cost.
         ``anytime`` additionally warms the k-plane prefix programs that
-        requests asking for those partial budgets will hit; an adaptive tier
+        requests asking for those partial budgets will hit, and the eager
+        calibration forward behind their error bounds; an adaptive tier
         warms every cascade stage program per bucket (a ``"calibrated"``
         tier must be calibrated first).  Returns the number of programs
         warmed (shared programs counted once)."""
@@ -1079,4 +1080,11 @@ class DslrServer:
                     jax.block_until_ready(engine(xb))
                     self.program_keys.add(key)
                     warmed.add(key)
+            if anytime:
+                # the anytime bounds' calibration forward runs op by op, and
+                # its first run at an image shape compiles every one of them
+                engine = self._engine_for(policy)
+                for b in warm_buckets:
+                    xb = jnp.zeros((b,) + tuple(image_shape), jnp.float32)
+                    self._anytime_bounds(engine, xb, anytime)
         return len(warmed)

@@ -21,6 +21,8 @@ The contracts under test, in interpret mode on CPU:
   * **Anytime partials are sound** — each k-digit partial's reported error
     bound dominates the measured deviation from the full-budget result.
 """
+import logging
+
 import numpy as np
 import pytest
 import jax
@@ -276,3 +278,25 @@ def test_warmup_precompiles_every_bucket_program(monkeypatch):
                       slo="only")
     server.flush()
     assert calls["n"] == 2  # steady-state traffic compiles nothing new
+
+
+def test_warmup_compiles_the_anytime_bounds_forward(caplog):
+    # unique shapes/classes so this test owns its compile cache entries.  The
+    # anytime bounds' calibration forward runs op by op; warmup must have
+    # compiled its conv kernels, or the first served wave compiles them
+    cfg, params, _ = setup(width=0.04, classes=7, img=11)
+    engine = compile_cnn(cfg, params, ExecutionPolicy())
+    server = DslrServer(
+        engine, slos=(), buckets=(2,), policies={"only": ExecutionPolicy()}
+    )
+    server.warmup((11, 11, 3), anytime=(2,))
+    img = jnp.asarray(np.random.default_rng(0).standard_normal((11, 11, 3)), jnp.float32)
+    with caplog.at_level(logging.WARNING, logger="jax"), jax.log_compiles(True):
+        h = server.submit(img, slo="only", anytime=(2,))
+        server.flush()
+    assert len(h.partials) == 1 and h.partials[0].bound > 0.0
+    kernels = [
+        r.getMessage() for r in caplog.records
+        if "Finished XLA compilation" in r.getMessage() and "dslr_conv2d" in r.getMessage()
+    ]
+    assert kernels == []
