@@ -231,10 +231,15 @@ def dslr_conv2d_planes_flat(
     scan (``ref.planes_scan_flat_ref``) instead of the Pallas kernel — the
     serving guardrails' trusted fallback, bitwise-identical to a healthy
     kernel (quantize / pack / im2col / scale folding are shared; only the
-    plane-accumulation launch differs)."""
+    plane-accumulation launch differs).
+
+    Each step runs in its own name scope (``quantize``, ``pack``,
+    ``im2col``, ``kernel``), so a device operation's ``op_name`` says which
+    step of the conv it belongs to."""
     if interpret is None:
         interpret = _on_cpu()
-    q = core_dslr.quantize_conv_planes(x, n_digits, recoding, per_sample=per_sample)
+    with jax.named_scope("quantize"):
+        q = core_dslr.quantize_conv_planes(x, n_digits, recoding, per_sample=per_sample)
     n_planes = q.planes.shape[0]
     if digit_budget is not None and not 1 <= digit_budget <= n_planes:
         raise ValueError(f"digit_budget={digit_budget} outside [1, {n_planes}]")
@@ -244,16 +249,18 @@ def dslr_conv2d_planes_flat(
         # gather because the zero digit encodes as a zero byte), so the big
         # materialized patch tensor is born packed: ceil(D/4) bytes per
         # patch element instead of D
-        image = dig.pack_planes(q.planes)
+        with jax.named_scope("pack"):
+            image = dig.pack_planes(q.planes)
     else:
         image = q.planes
-    patches = core_dslr.im2col_planes(image, kernel_size, stride, padding)
-    # digit-budget truncation: a leading-axis slice either way (nibble
-    # granularity when packed — residual digits in the last byte are simply
-    # never unpacked by the kernel)
-    patches = patches[: dig.packed_group_count(D) if packed else D]
-    _, B, Ho, Wo, T = patches.shape
-    planes = patches.reshape(patches.shape[0], B * Ho * Wo, T)
+    with jax.named_scope("im2col"):
+        patches = core_dslr.im2col_planes(image, kernel_size, stride, padding)
+        # digit-budget truncation: a leading-axis slice either way (nibble
+        # granularity when packed — residual digits in the last byte are
+        # simply never unpacked by the kernel)
+        patches = patches[: dig.packed_group_count(D) if packed else D]
+        _, B, Ho, Wo, T = patches.shape
+        planes = patches.reshape(patches.shape[0], B * Ho * Wo, T)
     fused = bias is not None or relu
     scales = core_dslr.digit_scales(D)
     row_scale = None
@@ -266,41 +273,42 @@ def dslr_conv2d_planes_flat(
         # Ho*Wo pixel block shares its sample's scale), multiplied into the
         # accumulator at the flush step before the bias lands
         row_scale = jnp.repeat(q.scale.astype(jnp.float32), Ho * Wo)
-    if use_ref:
-        out = _ref.planes_scan_flat_ref(
-            planes,
-            w_flat,
-            scales,
-            n_planes=D,
-            packed=packed,
-            bias=bias,
-            row_scale=row_scale,
-            apply_relu=relu,
-        )
-    else:
-        if block_m is None or block_n is None:
-            tuned_m, tuned_n = tuning.autotune_conv_blocks(
-                B * Ho * Wo, w_flat.shape[1], T, D, packed=packed, interpret=interpret
+    with jax.named_scope("kernel"):
+        if use_ref:
+            out = _ref.planes_scan_flat_ref(
+                planes,
+                w_flat,
+                scales,
+                n_planes=D,
+                packed=packed,
+                bias=bias,
+                row_scale=row_scale,
+                apply_relu=relu,
             )
-            block_m = block_m if block_m is not None else tuned_m
-            block_n = block_n if block_n is not None else tuned_n
-        kernel = (
-            _dc.dslr_conv2d_planes_packed_mxu
-            if packed
-            else _dc.dslr_conv2d_planes_mxu
-        )
-        out = kernel(
-            planes,
-            w_flat,
-            scales,
-            bias=bias,
-            row_scale=row_scale,
-            block_m=block_m,
-            block_n=block_n,
-            skip_zero_planes=skip_zero_planes,
-            apply_relu=relu,
-            interpret=interpret,
-        )
+        else:
+            if block_m is None or block_n is None:
+                tuned_m, tuned_n = tuning.autotune_conv_blocks(
+                    B * Ho * Wo, w_flat.shape[1], T, D, packed=packed, interpret=interpret
+                )
+                block_m = block_m if block_m is not None else tuned_m
+                block_n = block_n if block_n is not None else tuned_n
+            kernel = (
+                _dc.dslr_conv2d_planes_packed_mxu
+                if packed
+                else _dc.dslr_conv2d_planes_mxu
+            )
+            out = kernel(
+                planes,
+                w_flat,
+                scales,
+                bias=bias,
+                row_scale=row_scale,
+                block_m=block_m,
+                block_n=block_n,
+                skip_zero_planes=skip_zero_planes,
+                apply_relu=relu,
+                interpret=interpret,
+            )
     out = out.reshape(B, Ho, Wo, w_flat.shape[1])
     if not fused:
         s = q.scale.reshape(-1, 1, 1, 1) if per_sample else q.scale

@@ -204,58 +204,62 @@ def execute_graph(
     for node in graph.nodes:
         if node.name in vals:  # produced by a fused conv→conv pair
             continue
-        a = vals[node.inputs[0]]
-        if node.op in ("conv", "downsample"):
-            if weights is not None:
-                # engine path: only the flattened stationary copy is used (the
-                # raw 'w' leaves are stripped from the planes-mode param tree)
-                w = None
-                w_flat, b = weights[node.name]
-            elif policy.mode == "dslr_planes":
-                w = params[node.param]["w"]
-                w_flat, b = core_dslr.flatten_conv_weights(w), params[node.param]["b"]
-            else:
-                w = params[node.param]["w"]
-                w_flat, b = None, params[node.param]["b"]
-            epilogue = graph.epilogue_of(node)
-            if node.name in pair_for:
-                b_node = graph.node(pair_for[node.name])
+        # one name scope per node (a fused pair's is "<a>+<b>"): every device
+        # operation's op_name then says which node it belongs to
+        scope = f"{node.name}+{pair_for[node.name]}" if node.name in pair_for else node.name
+        with jax.named_scope(scope):
+            a = vals[node.inputs[0]]
+            if node.op in ("conv", "downsample"):
                 if weights is not None:
-                    w2_flat, b2 = weights[b_node.name]
+                    # engine path: only the flattened stationary copy is used (the
+                    # raw 'w' leaves are stripped from the planes-mode param tree)
+                    w = None
+                    w_flat, b = weights[node.name]
+                elif policy.mode == "dslr_planes":
+                    w = params[node.param]["w"]
+                    w_flat, b = core_dslr.flatten_conv_weights(w), params[node.param]["b"]
                 else:
-                    w2_flat = core_dslr.flatten_conv_weights(params[b_node.param]["w"])
-                    b2 = params[b_node.param]["b"]
-                out, wit = _fused_pair(
-                    graph, node, b_node, a, w_flat, b, w2_flat, b2, policy
-                )
-                vals[node.name] = wit
-                vals[epilogue.name] = wit
-                vals[b_node.name] = out
-                vals[graph.epilogue_of(b_node).name] = out
-                continue
-            vals[node.name] = _conv_node(node, a, w, w_flat, b, policy, epilogue)
-            if (
-                policy.mode == "dslr_planes"
-                and policy.fuse_epilogue
-                and epilogue is not None
-            ):
-                fused_done.add(epilogue.name)
-        elif node.op == "bias_relu":
-            if node.name in fused_done:  # already applied inside the kernel
-                vals[node.name] = a
+                    w = params[node.param]["w"]
+                    w_flat, b = None, params[node.param]["b"]
+                epilogue = graph.epilogue_of(node)
+                if node.name in pair_for:
+                    b_node = graph.node(pair_for[node.name])
+                    if weights is not None:
+                        w2_flat, b2 = weights[b_node.name]
+                    else:
+                        w2_flat = core_dslr.flatten_conv_weights(params[b_node.param]["w"])
+                        b2 = params[b_node.param]["b"]
+                    out, wit = _fused_pair(
+                        graph, node, b_node, a, w_flat, b, w2_flat, b2, policy
+                    )
+                    vals[node.name] = wit
+                    vals[epilogue.name] = wit
+                    vals[b_node.name] = out
+                    vals[graph.epilogue_of(b_node).name] = out
+                    continue
+                vals[node.name] = _conv_node(node, a, w, w_flat, b, policy, epilogue)
+                if (
+                    policy.mode == "dslr_planes"
+                    and policy.fuse_epilogue
+                    and epilogue is not None
+                ):
+                    fused_done.add(epilogue.name)
+            elif node.op == "bias_relu":
+                if node.name in fused_done:  # already applied inside the kernel
+                    vals[node.name] = a
+                else:
+                    out = a + params[node.param]["b"]
+                    vals[node.name] = jax.nn.relu(out) if node.relu else out
+            elif node.op == "maxpool":
+                vals[node.name] = _maxpool(a, node.kernel, node.stride, node.padding)
+            elif node.op == "avgpool":
+                vals[node.name] = jnp.mean(a, axis=(1, 2))  # kernel=0: global
+            elif node.op == "residual_add":
+                vals[node.name] = jax.nn.relu(a + vals[node.inputs[1]])
+            elif node.op == "dense":
+                vals[node.name] = cm.dense(params[node.param], a)
             else:
-                out = a + params[node.param]["b"]
-                vals[node.name] = jax.nn.relu(out) if node.relu else out
-        elif node.op == "maxpool":
-            vals[node.name] = _maxpool(a, node.kernel, node.stride, node.padding)
-        elif node.op == "avgpool":
-            vals[node.name] = jnp.mean(a, axis=(1, 2))  # kernel=0: global
-        elif node.op == "residual_add":
-            vals[node.name] = jax.nn.relu(a + vals[node.inputs[1]])
-        elif node.op == "dense":
-            vals[node.name] = cm.dense(params[node.param], a)
-        else:
-            raise ValueError(f"unknown node op {node.op!r}")
+                raise ValueError(f"unknown node op {node.op!r}")
     if return_all:
         return vals
     return vals[graph.nodes[-1].name]

@@ -59,6 +59,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 
 class ServerOverloaded(RuntimeError):
     """Raised by ``submit`` when admission control projects that the request
@@ -108,7 +110,9 @@ class Dispatcher:
 
     ``dispatch`` is the server's wave executor: it receives a list of
     :class:`QueuedRequest` sharing one ``group_key`` and must complete (or
-    fail) every handle in it.  The dispatcher never touches jax itself.
+    fail) every handle in it.  The dispatcher never touches jax itself,
+    beyond the profiler spans around its waits (``dslr.dispatch.wait``,
+    ``dslr.dispatch.backoff``), which record only while a trace runs.
     """
 
     def __init__(
@@ -150,7 +154,6 @@ class Dispatcher:
         self._retries = 0  # failed dispatch attempts that were retried
         self._quarantined = 0  # requests isolated by bisection
         self._restarts = 0  # worker-thread resurrections
-        self.wave_seq = 0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -376,8 +379,12 @@ class Dispatcher:
                     self._restarts += 1
 
     def _run(self) -> None:
+        # ``dslr.dispatch.wait`` spans cover the worker between waves: taking
+        # the next wave or waiting for one to fill, and the bookkeeping after
+        # a wave.  Each wake-up opens one of its own as well, because a span
+        # opened before a trace began records nothing.
         while True:
-            with self._cond:
+            with TraceAnnotation("dslr.dispatch.wait"), self._cond:
                 wave = None
                 while wave is None:
                     if not self._running and not self._pending:
@@ -386,9 +393,9 @@ class Dispatcher:
                     now = time.monotonic()
                     wave = self._take_wave(now)
                     if wave is None:
-                        self._cond.wait(self._wait_timeout(now))
+                        with TraceAnnotation("dslr.dispatch.wait"):
+                            self._cond.wait(self._wait_timeout(now))
                 self._inflight += len(wave)
-                self.wave_seq += 1
             t0 = time.monotonic()
             try:
                 self._run_wave(wave)
@@ -415,7 +422,7 @@ class Dispatcher:
                 raise
             finally:
                 per_req = (time.monotonic() - t0) / len(wave)
-                with self._cond:
+                with TraceAnnotation("dslr.dispatch.wait"), self._cond:
                     self._inflight -= len(wave)
                     if self._service_ema_s is None:
                         self._service_ema_s = per_req
@@ -464,9 +471,10 @@ class Dispatcher:
                 with self._cond:
                     self._retries += 1
             if max(r.retries for r in live) <= self._max_retries:
-                time.sleep(
-                    min(self._backoff_base_s * 2.0**attempt, self._backoff_cap_s)
-                )
+                with TraceAnnotation("dslr.dispatch.backoff", attempt=attempt):
+                    time.sleep(
+                        min(self._backoff_base_s * 2.0**attempt, self._backoff_cap_s)
+                    )
                 attempt += 1
                 continue
             break

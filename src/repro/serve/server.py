@@ -54,6 +54,14 @@ drains + joins it on exit; explicitly, ``start()`` / ``drain()`` /
 synchronous path: ``flush()`` drains the queue in the submitting thread and
 ``handle.result()`` flushes on demand — the reference the async path is
 asserted bitwise against.
+
+Profiling: the serving path opens ``jax.profiler.TraceAnnotation`` spans,
+which record only while a profiler session runs — ``dslr.submit`` per
+request (``request_id``), ``dslr.wave`` per wave (``rows``, ``bucket``,
+``request_ids``) around its steps ``dslr.wave.assemble``, ``.launch``,
+``.anytime``, ``.guard`` and ``.deliver``, and the dispatcher's
+``dslr.dispatch.wait`` and ``dslr.dispatch.backoff``.  They put the host's
+per-wave work on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -65,6 +73,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.adaptive import (
     Cascade,
@@ -205,6 +214,25 @@ class ResultHandle:
         self._cancelled = True
         self.done_time = time.monotonic()
         self._event.set()
+
+
+def _wave_span(chunk: List[QueuedRequest], bucket: int) -> TraceAnnotation:
+    """The ``dslr.wave`` profiler span of one wave.  Its request ids (space
+    separated) are formatted only while a trace records, so that a request's
+    ``dslr.submit`` span can be joined to its wave at no cost otherwise."""
+    if TraceAnnotation.is_enabled():
+        ids = " ".join(str(r.request_id) for r in chunk)
+        return TraceAnnotation("dslr.wave", rows=len(chunk), bucket=bucket, request_ids=ids)
+    return TraceAnnotation("dslr.wave", rows=len(chunk), bucket=bucket)
+
+
+def _assemble(chunk: List[QueuedRequest], bucket: int) -> jax.Array:
+    """Stack a wave's images and zero-pad the batch up to its bucket."""
+    with TraceAnnotation("dslr.wave.assemble"):
+        xb = jnp.stack([r.image for r in chunk])
+        if bucket > len(chunk):
+            xb = jnp.pad(xb, ((0, bucket - len(chunk)), (0, 0), (0, 0), (0, 0)))
+        return xb
 
 
 class DslrServer:
@@ -595,6 +623,18 @@ class DslrServer:
         ``ServerOverloaded`` when the projected queue dwell exceeds the
         budget); on a never-started server, ``handle.result()`` or an
         explicit ``flush()`` dispatches synchronously."""
+        with TraceAnnotation("dslr.submit") as span:
+            handle = self._submit(image, slo, anytime, deadline_ms)
+            span.set_metadata(request_id=handle.request_id)
+        return handle
+
+    def _submit(
+        self,
+        image: jax.Array,
+        slo: str,
+        anytime: Sequence[int],
+        deadline_ms: Optional[float],
+    ) -> ResultHandle:
         if self._dispatcher.closed:
             raise RuntimeError("server is closed; build a new DslrServer")
         image = jnp.asarray(image, jnp.float32)
@@ -740,18 +780,21 @@ class DslrServer:
                     self._dispatch_wave(chunk)
 
     def _dispatch_wave(self, chunk: List[QueuedRequest]) -> None:
-        """Execute one wave (all requests share a (policy, shape) group key).
-        Runs on the dispatcher thread (async) or the caller (sync flush)."""
-        if chunk[0].group_key[0] == "adaptive":
-            self._dispatch_adaptive_wave(chunk)
-            return
-        policy = chunk[0].group_key[0]
+        """Execute one wave (all requests share one group key).  Runs on the
+        dispatcher thread (async) or the caller (sync flush), inside the
+        wave's ``dslr.wave`` profiler span."""
         bucket = self._bucket_for(len(chunk))
-        xb = jnp.stack([r.image for r in chunk])
-        if bucket > len(chunk):
-            xb = jnp.pad(
-                xb, ((0, bucket - len(chunk)), (0, 0), (0, 0), (0, 0))
-            )
+        with _wave_span(chunk, bucket):
+            if chunk[0].group_key[0] == "adaptive":
+                self._dispatch_adaptive_wave(chunk, bucket)
+            else:
+                self._dispatch_plain_wave(chunk, bucket)
+
+    def _dispatch_plain_wave(self, chunk: List[QueuedRequest], bucket: int) -> None:
+        """One wave of a non-adaptive tier: every request shares the
+        ``(policy, shape)`` group key and rides the same program."""
+        policy = chunk[0].group_key[0]
+        xb = _assemble(chunk, bucket)
         # anytime channel budgets: one prefix program per distinct requested
         # budget in this wave (per-sample scales make the grouping invisible
         # to each request's values)
@@ -789,50 +832,51 @@ class DslrServer:
                 for n in self._donor.graph.conv_nodes
             )
 
-        with self._lock:
-            self.stats["dispatches"] += 1
-            self.stats["padded_rows"] += bucket - len(chunk)
-            self.stats["degraded"] += sum(
-                1 for r in chunk if r.brownout_k is not None
-            )
-            self.program_keys.add((bucket, policy))
-            for k in ks:
-                pk = self._prefix_policy(policy, k)
-                if pk != policy:
-                    self.program_keys.add((bucket, pk))
-            self.wave_log.append(tuple(r.request_id for r in chunk))
-            wave_seq = len(self.wave_log)
+        with TraceAnnotation("dslr.wave.deliver"):
+            with self._lock:
+                self.stats["dispatches"] += 1
+                self.stats["padded_rows"] += bucket - len(chunk)
+                self.stats["degraded"] += sum(
+                    1 for r in chunk if r.brownout_k is not None
+                )
+                self.program_keys.add((bucket, policy))
+                for k in ks:
+                    pk = self._prefix_policy(policy, k)
+                    if pk != policy:
+                        self.program_keys.add((bucket, pk))
+                self.wave_log.append(tuple(r.request_id for r in chunk))
+                wave_seq = len(self.wave_log)
 
-        for i, r in enumerate(chunk):
-            partials = []
-            for k in r.anytime:
-                if r.brownout_k is not None:
-                    bound = tier_bounds[(r.slo, r.brownout_k)][
-                        min(k, r.brownout_k)
-                    ]
-                else:
-                    bound = bounds_by_k[k]
-                partials.append(
-                    AnytimeResult(
-                        budget=k,
-                        logits=partials_by_k[k][i],
-                        top1=int(jnp.argmax(partials_by_k[k][i])),
-                        bound=bound,
+            for i, r in enumerate(chunk):
+                partials = []
+                for k in r.anytime:
+                    if r.brownout_k is not None:
+                        bound = tier_bounds[(r.slo, r.brownout_k)][
+                            min(k, r.brownout_k)
+                        ]
+                    else:
+                        bound = bounds_by_k[k]
+                    partials.append(
+                        AnytimeResult(
+                            budget=k,
+                            logits=partials_by_k[k][i],
+                            top1=int(jnp.argmax(partials_by_k[k][i])),
+                            bound=bound,
+                        )
                     )
-                )
-            if r.brownout_k is not None:
-                key = (r.slo, r.brownout_k)
-                r.handle.degraded = True
-                r.handle.served_budget = r.brownout_k
-                r.handle.brownout_bound = tier_bounds[key][r.brownout_k]
-                r.handle._set_result(
-                    logits[i],
-                    tuple(partials),
-                    wave_seq,
-                    digits_spent=tier_digits[key],
-                )
-            else:
-                r.handle._set_result(logits[i], tuple(partials), wave_seq)
+                if r.brownout_k is not None:
+                    key = (r.slo, r.brownout_k)
+                    r.handle.degraded = True
+                    r.handle.served_budget = r.brownout_k
+                    r.handle.brownout_bound = tier_bounds[key][r.brownout_k]
+                    r.handle._set_result(
+                        logits[i],
+                        tuple(partials),
+                        wave_seq,
+                        digits_spent=tier_digits[key],
+                    )
+                else:
+                    r.handle._set_result(logits[i], tuple(partials), wave_seq)
 
     def _guarded_wave(
         self,
@@ -852,7 +896,8 @@ class DslrServer:
         inj = self._fault_injector
         engine = self._engine_for(policy)
         for attempt in range(2):
-            logits = engine(xb)
+            with TraceAnnotation("dslr.wave.launch", attempt=attempt):
+                logits = engine(xb)
             if inj is not None:
                 logits = inj.corrupt_logits(logits, key=wave_ids + (attempt,))
             partials_by_k, bounds_by_k = self._anytime_partials(
@@ -865,7 +910,8 @@ class DslrServer:
         # both kernel runs suspect: fall back to the trusted oracle (no
         # injection on this path — it models the known-good slow engine)
         oracle_policy = dataclasses.replace(policy, use_ref=True)
-        logits = self._engine_for(oracle_policy)(xb)
+        with TraceAnnotation("dslr.wave.launch", attempt=2):
+            logits = self._engine_for(oracle_policy)(xb)
         partials_by_k, bounds_by_k = self._anytime_partials(
             oracle_policy, xb, ks, logits
         )
@@ -884,7 +930,9 @@ class DslrServer:
         for one wave (empty dicts when no budgets were requested)."""
         partials_by_k: Dict[int, jax.Array] = {}
         bounds_by_k: Dict[int, float] = {}
-        if ks:
+        if not ks:
+            return partials_by_k, bounds_by_k
+        with TraceAnnotation("dslr.wave.anytime", budgets=" ".join(map(str, ks))):
             bounds_by_k = self._anytime_bounds(self._engine_for(policy), xb, ks)
             for k in ks:
                 pk = self._prefix_policy(policy, k)
@@ -904,20 +952,22 @@ class DslrServer:
         """The output guardrails: finite logits/partials, and every anytime
         partial within its sound bound of the full answer (a violated bound
         is *proof* of a miscomputation — the bound is an upper bound by
-        construction, so a healthy wave cannot trip it)."""
-        if not bool(jnp.all(jnp.isfinite(logits))):
-            return False
-        for k, part in partials_by_k.items():
-            if not bool(jnp.all(jnp.isfinite(part))):
+        construction, so a healthy wave cannot trip it).  The first point
+        where the host blocks on the wave's device work."""
+        with TraceAnnotation("dslr.wave.guard"):
+            if not bool(jnp.all(jnp.isfinite(logits))):
                 return False
-            bound = bounds_by_k.get(k)
-            if bound is not None and bound > 0.0:
-                measured = float(jnp.max(jnp.abs(part - logits)))
-                if measured > bound:
+            for k, part in partials_by_k.items():
+                if not bool(jnp.all(jnp.isfinite(part))):
                     return False
-        return True
+                bound = bounds_by_k.get(k)
+                if bound is not None and bound > 0.0:
+                    measured = float(jnp.max(jnp.abs(part - logits)))
+                    if measured > bound:
+                        return False
+            return True
 
-    def _dispatch_adaptive_wave(self, chunk: List[QueuedRequest]) -> None:
+    def _dispatch_adaptive_wave(self, chunk: List[QueuedRequest], bucket: int) -> None:
         """One cascade-stage wave of a confidence-gated tier: run the stage
         program on the whole (bucket-padded) wave, complete the decided
         requests with the stage's logits, and escalate the undecided tail —
@@ -929,61 +979,59 @@ class DslrServer:
         slo, stage_idx = chunk[0].slo, chunk[0].stage_idx
         cascade = self.cascade_for(slo)
         stage = cascade.stages[stage_idx]
-        bucket = self._bucket_for(len(chunk))
-        xb = jnp.stack([r.image for r in chunk])
-        if bucket > len(chunk):
-            xb = jnp.pad(
-                xb, ((0, bucket - len(chunk)), (0, 0), (0, 0), (0, 0))
-            )
-        logits, amax = cascade.run_stage(stage, xb)
+        xb = _assemble(chunk, bucket)
+        with TraceAnnotation("dslr.wave.launch", attempt=0):
+            logits, amax = cascade.run_stage(stage, xb)
         n = len(chunk)
-        dec, _, _ = cascade.decide(
-            stage, logits[:n], None if amax is None else amax[:, :n]
-        )
-
-        with self._lock:
-            self.stats["dispatches"] += 1
-            self.stats["padded_rows"] += bucket - n
-            # a prefix-stage program is distinct from the plain program of
-            # the same policy (it also returns the per-layer amax), so it
-            # gets its own key; the final stage IS the plain program
-            key = (
-                (bucket, stage.policy)
-                if stage.final
-                else (bucket, stage.policy, "stage")
+        with TraceAnnotation("dslr.wave.guard"):
+            dec, _, _ = cascade.decide(
+                stage, logits[:n], None if amax is None else amax[:, :n]
             )
-            self.program_keys.add(key)
-            self.wave_log.append(tuple(r.request_id for r in chunk))
-            wave_seq = len(self.wave_log)
 
-        escalate: List[QueuedRequest] = []
-        n_exits = 0
-        for i, r in enumerate(chunk):
-            r.digits_spent += stage.planes_cost
-            if dec[i]:
-                n_exits += not stage.final
-                r.handle._set_result(
-                    logits[i],
-                    (),
-                    wave_seq,
-                    digits_spent=r.digits_spent,
-                    decided_at_stage=stage.index,
+        with TraceAnnotation("dslr.wave.deliver"):
+            with self._lock:
+                self.stats["dispatches"] += 1
+                self.stats["padded_rows"] += bucket - n
+                # a prefix-stage program is distinct from the plain program of
+                # the same policy (it also returns the per-layer amax), so it
+                # gets its own key; the final stage IS the plain program
+                key = (
+                    (bucket, stage.policy)
+                    if stage.final
+                    else (bucket, stage.policy, "stage")
                 )
-            else:
-                r.stage_idx += 1
-                r.group_key = (
-                    "adaptive", r.slo, r.stage_idx, tuple(r.image.shape)
-                )
-                escalate.append(r)
-        with self._lock:
-            self.stats["early_exits"] += n_exits
-            self.stats["escalated"] += len(escalate)
-        if escalate:
-            if self.running:
-                self._dispatcher.requeue(escalate)
-            else:
-                with self._lock:
-                    self._queue.extend(escalate)
+                self.program_keys.add(key)
+                self.wave_log.append(tuple(r.request_id for r in chunk))
+                wave_seq = len(self.wave_log)
+
+            escalate: List[QueuedRequest] = []
+            n_exits = 0
+            for i, r in enumerate(chunk):
+                r.digits_spent += stage.planes_cost
+                if dec[i]:
+                    n_exits += not stage.final
+                    r.handle._set_result(
+                        logits[i],
+                        (),
+                        wave_seq,
+                        digits_spent=r.digits_spent,
+                        decided_at_stage=stage.index,
+                    )
+                else:
+                    r.stage_idx += 1
+                    r.group_key = (
+                        "adaptive", r.slo, r.stage_idx, tuple(r.image.shape)
+                    )
+                    escalate.append(r)
+            with self._lock:
+                self.stats["early_exits"] += n_exits
+                self.stats["escalated"] += len(escalate)
+            if escalate:
+                if self.running:
+                    self._dispatcher.requeue(escalate)
+                else:
+                    with self._lock:
+                        self._queue.extend(escalate)
 
     # -- anytime error bounds --------------------------------------------------
 
