@@ -310,16 +310,32 @@ def packed_plane_activity(packed: jax.Array, n_digits: int, tile_rows: int) -> j
     digit plane d of row tile m has any non-zero digit — exactly the
     ``jnp.any(plane != 0)`` predicate of the zero-plane-skipping kernel,
     hoisted out of the kernel so a dead (tile, digit) is known *before* its
-    bytes would be DMA'd into VMEM.  The hoist is not free — the kernel
-    wrapper runs this (XLA-fused) reduce over the packed operand once per
-    launch — but it reads the 4x-narrower packed bytes, where the in-kernel
-    probe it replaces DMA'd every unpacked tile just to test it.
+    bytes would be DMA'd into VMEM.
+
+    One bitwise-OR reduction over each byte group's row tile reads the
+    packed operand once and writes G bytes a tile; the bitmap is then split
+    out of those bytes.  Exact because the zero digit is the field 0b00 and
+    both nonzero digits (0b01, 0b11) are nonzero fields: field j % 4 of the
+    OR of a tile's bytes is nonzero iff digit j is nonzero somewhere in the
+    tile.  Nothing is indexed by digit, so no (n_digits, M, T) copy of the
+    operand is ever materialised.
     """
-    G, M, T = packed.shape
+    _, M, T = packed.shape
     if M % tile_rows:
         raise ValueError(f"M={M} not a multiple of tile_rows={tile_rows}")
-    j = np.arange(n_digits)
-    shift = jnp.asarray(2 * (j % 4)).reshape(-1, 1, 1, 1)
-    tiles = packed[jnp.asarray(j // 4)].reshape(n_digits, M // tile_rows, tile_rows, T)
-    live = ((tiles.astype(jnp.int32) >> shift) & 3) != 0
-    return jnp.any(live, axis=(2, 3)).astype(jnp.int32).T
+    G = packed_group_count(n_digits)
+    if not 1 <= G <= packed.shape[0]:
+        raise ValueError(f"n_digits={n_digits} outside [1, {4 * packed.shape[0]}]")
+    Mt = M // tile_rows
+    # the barrier keeps XLA from pushing the reduction back through the ops
+    # that built ``packed`` (an im2col's slices of the image), where it
+    # reduces each slice over its channels, several times slower than one
+    # read of the matrix the kernel reads anyway
+    tiles = jax.lax.optimization_barrier(packed[:G]).reshape(G, Mt, tile_rows, T)
+    orr = jax.lax.reduce(tiles, jnp.int8(0), jax.lax.bitwise_or, (2, 3))  # (G, Mt)
+    # widen before shifting: a -1 in field 3 makes the byte negative, and
+    # & 3 drops the sign-extension bits
+    shift = 2 * jnp.arange(PACK_DIGITS_PER_BYTE, dtype=jnp.int32)
+    fields = (orr.astype(jnp.int32)[..., None] >> shift) & 3  # (G, Mt, 4)
+    live = fields.transpose(1, 0, 2).reshape(Mt, PACK_DIGITS_PER_BYTE * G)
+    return (live[:, :n_digits] != 0).astype(jnp.int32)

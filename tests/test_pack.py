@@ -81,21 +81,48 @@ def test_unpack_validates_digit_count():
         dig.unpack_planes(packed, 0)
 
 
-@given(st.integers(min_value=0, max_value=10**6))
-@settings(max_examples=15, deadline=None)
-def test_packed_plane_activity_matches_any_nonzero(seed):
-    rng = np.random.default_rng(seed)
-    D = int(rng.integers(1, 13))
-    M, T, bm = 16, 5, 8
-    planes = rng.choice(np.array([-1, 0, 1], np.int8), size=(D, M, T),
-                        p=[1 / 6, 2 / 3, 1 / 6])
-    # force some fully dead (tile, digit) pairs
-    planes[0, :bm] = 0
-    act = dig.packed_plane_activity(dig.pack_planes(jnp.asarray(planes)), D, bm)
+# (M, T, tile_rows): a small matrix of two 8-row tiles, then ResNet-18's patch
+# widths (C1's 7x7x3, a 3x3x64 and a 3x3x512 conv) at three 128-row tiles
+ACTIVITY_SHAPES = [(16, 5, 8), (384, 147, 128), (384, 576, 128), (384, 4608, 128)]
+
+
+def _activity_planes(M, T, bm, D, rng):
+    """Digit planes whose (tile, digit) pairs are each dead, hold one lone
+    -1 or +1, or are dense at random; tile 0 holds a lone -1 in every digit
+    (each field reads 0b11 in some byte, and a -1 in digit 3 makes its byte
+    negative), and tile 1 has the last byte group dead while tile 0 has it
+    live."""
+    planes = np.zeros((D, M, T), np.int8)
+    for m in range(M // bm):
+        for d in range(D):
+            kind = "lone-1" if m == 0 else rng.choice(["dead", "lone-1", "lone+1", "dense"])
+            if m == 1 and d >= 4 * (dig.packed_group_count(D) - 1):
+                kind = "dead"
+            rows = slice(m * bm, (m + 1) * bm)
+            if kind == "dense":
+                planes[d, rows] = rng.choice(np.array([-1, 0, 1], np.int8), size=(bm, T),
+                                             p=[1 / 6, 2 / 3, 1 / 6])
+            elif kind != "dead":
+                r, c = rng.integers(bm), rng.integers(T)
+                planes[d, m * bm + r, c] = -1 if kind == "lone-1" else 1
+    return planes
+
+
+@pytest.mark.parametrize("n_digits", range(1, 13))
+@pytest.mark.parametrize("shape", ACTIVITY_SHAPES, ids=lambda s: "M{}-T{}-bm{}".format(*s))
+def test_packed_plane_activity_matches_any_nonzero(shape, n_digits):
+    M, T, bm = shape
+    D = n_digits
+    planes = _activity_planes(M, T, bm, D, np.random.default_rng([M, T, D]))
+    packed = dig.pack_planes(jnp.asarray(planes))
+    if D >= 4:  # tile 0's -1 in digit 3 makes a negative byte
+        assert np.any(np.asarray(packed) < 0)
+    act = dig.packed_plane_activity(packed, D, bm)
     want = np.stack([
         [int(np.any(planes[d, mt * bm:(mt + 1) * bm] != 0)) for d in range(D)]
         for mt in range(M // bm)
     ])
+    assert want[0].all() and not want[1, -1]  # a group live in one tile, dead in another
     np.testing.assert_array_equal(np.asarray(act), want)
 
 
@@ -103,6 +130,14 @@ def test_packed_plane_activity_rejects_ragged_tiles():
     packed = dig.pack_planes(jnp.zeros((4, 10, 3), jnp.int8))
     with pytest.raises(ValueError):
         dig.packed_plane_activity(packed, 4, 8)
+
+
+def test_packed_plane_activity_rejects_digits_beyond_its_groups():
+    packed = dig.pack_planes(jnp.zeros((5, 8, 3), jnp.int8))  # 2 groups
+    with pytest.raises(ValueError):
+        dig.packed_plane_activity(packed, 9, 8)
+    with pytest.raises(ValueError):
+        dig.packed_plane_activity(packed, 0, 8)
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,10 @@
 Interpret mode never checks Mosaic's tiling rules, so these tests lower and
 compile the kernels with ``interpret=False`` at the widths that are served:
 ResNet-18's first and last conv geometries through the packed conv path
-(per-sample row scales, fused bias and ReLU), the unpacked conv kernel, and
-the packed matmul at qwen2-0.5b decode and prefill widths.  No chip is
+(per-sample row scales, fused bias and ReLU), the packed conv wrapper at the
+served bucket-8 patch matrices (its dead-plane bitmap copies no digit
+views), the unpacked conv kernel, and the packed matmul at qwen2-0.5b
+decode and prefill widths.  No chip is
 needed — the TPU compiler targets a topology that is described, not
 attached — and nothing runs.
 
@@ -15,6 +17,7 @@ would leave the other workers collecting different tests.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,10 +71,8 @@ RESNET18_CONVS = [
 ]
 
 
-@pytest.mark.parametrize("budget", [None, 4], ids=["D9", "k4"])
-@pytest.mark.parametrize("geom", RESNET18_CONVS, ids=[g[0] for g in RESNET18_CONVS])
-def test_packed_conv_compiles_for_v5e(one_chip, geom, budget):
-    _, x_shape, k, stride, pad, cout = geom
+def _packed_conv_text(sharding, x_shape, k, stride, pad, cout, budget=None) -> str:
+    """The whole packed conv compiled: quantize, pack, im2col and kernel."""
     T = k * k * x_shape[-1]
     fn = functools.partial(
         ops.dslr_conv2d_planes_flat,
@@ -85,12 +86,82 @@ def test_packed_conv_compiles_for_v5e(one_chip, geom, budget):
         packed=True,
         interpret=False,
     )
-    text = _compile_text(
+    return _compile_text(
         lambda x, w, b: fn(x, w, bias=b),
         [(x_shape, jnp.float32), ((T, cout), jnp.float32), ((cout,), jnp.float32)],
+        sharding,
+    )
+
+
+@pytest.mark.parametrize("budget", [None, 4], ids=["D9", "k4"])
+@pytest.mark.parametrize("geom", RESNET18_CONVS, ids=[g[0] for g in RESNET18_CONVS])
+def test_packed_conv_compiles_for_v5e(one_chip, geom, budget):
+    _, x_shape, k, stride, pad, cout = geom
+    text = _packed_conv_text(one_chip, x_shape, k, stride, pad, cout, budget)
+    assert text.count("tpu_custom_call") >= 1
+
+
+# (name, input (B, H, W, Cin), kernel, stride, padding): the served bucket-8
+# wave's C1 (M = 8*112*112 rows of T = 7*7*3), a layer1 conv (T = 3*3*64) and
+# a layer2 conv (T = 3*3*128)
+BUCKET8_CONVS = [
+    ("C1", (8, 224, 224, 3), 7, 2, 3),
+    ("C2", (8, 56, 56, 64), 3, 1, 1),
+    ("C8", (8, 28, 28, 128), 3, 1, 1),
+]
+
+
+@pytest.mark.parametrize("geom", BUCKET8_CONVS, ids=[g[0] for g in BUCKET8_CONVS])
+def test_packed_conv_bitmap_copies_no_digit_views(one_chip, geom):
+    """The dead-plane bitmap is one OR-reduction over the packed bytes: the
+    compiled conv wrapper holds no gather loop over the digit axis and no
+    (D, M, T) array of digit views.  Compiles the wrapper alone at the
+    packed patch matrix the geometry gives."""
+    _, (B, H, W, cin), k, stride, pad = geom
+    D = 9  # n_digits=8 serves 9 MSDF planes, in 3 byte groups
+    Ho, Wo = (H + 2 * pad - k) // stride + 1, (W + 2 * pad - k) // stride + 1
+    M, T, N = B * Ho * Wo, k * k * cin, 64
+    fn = functools.partial(dc.dslr_conv2d_planes_packed_mxu, apply_relu=True)
+    text = _compile_text(
+        lambda p, w, s, b, r: fn(p, w, s, bias=b, row_scale=r),
+        [
+            ((3, M, T), jnp.int8),
+            ((T, N), jnp.float32),
+            ((D,), jnp.float32),
+            ((N,), jnp.float32),
+            ((M,), jnp.float32),
+        ],
         one_chip,
     )
-    assert text.count("tpu_custom_call") >= 1
+    assert "tpu_custom_call" in text
+    gathers = [
+        line for line in text.splitlines()
+        if " while(" in line
+        and re.search(r'op_name="[^"]*dslr_conv2d_planes_packed_mxu[^"]*/gather"', line)
+    ]
+    assert not gathers, gathers[:1]
+    assert f"s8[{D},{M},{T}]" not in text
+
+
+@pytest.mark.parametrize("geom", BUCKET8_CONVS[1:], ids=[g[0] for g in BUCKET8_CONVS[1:]])
+def test_packed_conv_bitmap_reads_the_patch_matrix(one_chip, geom):
+    """The bitmap's OR-reduction reads the packed patch matrix the kernel
+    reads, and is not pushed back into the im2col's slices of the image,
+    where it would reduce every slice over its channels.  Not for C1, whose
+    bucket-8 prologue takes minutes to compile."""
+    _, x_shape, k, stride, pad = geom
+    text = _packed_conv_text(one_chip, x_shape, k, stride, pad, 64)
+    reduces = [
+        line.split(" = ", 1)[1].split(" reduce(")[0]
+        for line in text.splitlines()
+        if " reduce(" in line
+        and re.search(r'op_name="[^"]*dslr_conv2d_planes_packed_mxu\)/reduce"', line)
+    ]
+    assert reduces
+    # an image slice's reduce keeps the batch and both spatial axes
+    B, H = x_shape[0], (x_shape[1] + 2 * pad - k) // stride + 1
+    image_shaped = [r for r in reduces if f",{B},{H},{H}" in r]
+    assert not image_shaped, image_shaped[:2]
 
 
 def test_unpacked_conv_compiles_for_v5e(one_chip):
