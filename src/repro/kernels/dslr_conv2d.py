@@ -277,9 +277,55 @@ def plane_fetch_indices(activity: jax.Array, n_digits: int) -> jax.Array:
     return fetch.astype(jnp.int32)
 
 
+def table_slot(m, d, n_digits: int):
+    """Slot of (row tile ``m``, digit ``d``) in a flat scalar-prefetch table."""
+    return m * n_digits + d
+
+
+def plane_tables(activity: jax.Array, n_digits: int) -> tuple[jax.Array, jax.Array]:
+    """The packed kernels' two scalar-prefetch tables from an (Mt, D)
+    activity bitmap: the bitmap and ``plane_fetch_indices``, each flat
+    (Mt * D,) int32 and read at ``table_slot(m, d, D)``.
+
+    Flat because Mosaic pads the last dimension of an SMEM array to 128
+    words: an (Mt, D) table would take Mt * 512 bytes whatever D is, and at
+    VGG-16's 224x224 convs (Mt = 3136 at bucket 8) the pair would need 3.1
+    MiB of the 1 MiB SMEM.  Flat, the pair takes 8 * Mt * D bytes (220 KiB
+    there).  Shared with kernels/traffic.py, which replays the same reads.
+    """
+    act = activity.astype(jnp.int32).reshape(-1)
+    return act, plane_fetch_indices(activity, n_digits).reshape(-1)
+
+
+def packed_plane_tables(
+    packed: jax.Array, n_digits: int, tile_rows: int, skip_zero_planes: bool
+) -> tuple[jax.Array, jax.Array]:
+    """Flat (act, fetch) tables of a packed kernel launch over ``packed``
+    (G, M, T) at row tile ``tile_rows`` (``plane_tables``).  Without
+    skipping every digit's own group is resident (fetched once per 4 digits
+    either way, since consecutive digits share a group) and the kernel never
+    reads the bitmap, so none is computed."""
+    if skip_zero_planes:
+        return plane_tables(dig.packed_plane_activity(packed, n_digits, tile_rows), n_digits)
+    Mt = packed.shape[1] // tile_rows
+    act = jnp.zeros((Mt * n_digits,), jnp.int32)
+    fetch = jnp.tile(jnp.arange(n_digits, dtype=jnp.int32) // 4, Mt)
+    return act, fetch
+
+
+def packed_plane_index_map(n_digits: int):
+    """Index map of the packed plane operand's (1, bm, T) block: byte group
+    ``fetch[m, d]`` of row tile m, read from the flat tables."""
+
+    def index_map(m, n, d, act, fetch):
+        return fetch[table_slot(m, d, n_digits)], m, 0
+
+    return index_map
+
+
 def _dslr_conv2d_packed_kernel(
-    act_ref,  # SMEM (Mt, D) int32 — per-(tile, digit) nonzero bitmap
-    fetch_ref,  # SMEM (Mt, D) int32 — resident byte group per step (index map)
+    act_ref,  # SMEM (Mt * D,) int32 — per-(tile, digit) nonzero bitmap
+    fetch_ref,  # SMEM (Mt * D,) int32 — resident byte group per step (index map)
     packed_ref,  # (1, bm, T) int8 — byte group fetch[m, d] of the patches
     w_ref,  # (T, bn) f32 — stationary flattened filter tile
     scale_ref,  # SMEM (D,) f32 — digit weights, plane d reads scale_ref[d]
@@ -324,7 +370,7 @@ def _dslr_conv2d_packed_kernel(
     if skip_zero_planes:
         # the SMEM bitmap already knows a dead (tile, digit) — no byte was
         # DMA'd in to find out (cf. the unpacked kernel's jnp.any probe)
-        jax.lax.cond(act_ref[m, d] != 0, _accumulate, lambda: None)
+        jax.lax.cond(act_ref[table_slot(m, d, n_digits)] != 0, _accumulate, lambda: None)
     else:
         _accumulate()
 
@@ -409,22 +455,12 @@ def dslr_conv2d_planes_packed_mxu(
     if Np != N:
         wf = jnp.pad(wf, ((0, 0), (0, Np - N)))
 
-    if skip_zero_planes:
-        activity = dig.packed_plane_activity(packed, D, bm)  # (Mt, D) int32
-        fetch = plane_fetch_indices(activity, D)
-    else:
-        # no skipping: every digit's own group is resident (fetched once per
-        # 4 digits either way, since consecutive digits share a group); the
-        # kernel never reads the bitmap in this mode, so don't compute one
-        activity = jnp.zeros((Mp // bm, D), jnp.int32)
-        fetch = jnp.broadcast_to(
-            (jnp.arange(D, dtype=jnp.int32) // 4)[None, :], activity.shape
-        )
+    activity, fetch = packed_plane_tables(packed, D, bm, skip_zero_planes)
 
     has_row_scale = row_scale is not None
     has_bias = bias is not None
     in_specs = [
-        pl.BlockSpec((1, bm, T), lambda m, n, d, act, fetch: (fetch[m, d], m, 0)),
+        pl.BlockSpec((1, bm, T), packed_plane_index_map(D)),
         pl.BlockSpec((T, bn), lambda m, n, d, act, fetch: (0, n)),
         SMEM_WHOLE,
     ]

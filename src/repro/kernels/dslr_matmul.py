@@ -39,7 +39,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import digits as dig
 
 from . import tuning
-from .dslr_conv2d import DOT_PRECISION, SMEM_WHOLE, plane_fetch_indices
+from .dslr_conv2d import (
+    DOT_PRECISION,
+    SMEM_WHOLE,
+    packed_plane_index_map,
+    packed_plane_tables,
+    table_slot,
+)
 
 
 def _dslr_matmul_kernel(
@@ -138,8 +144,8 @@ def dslr_matmul_planes(
 
 
 def _dslr_matmul_packed_kernel(
-    act_ref,  # SMEM (Mt, D) int32 — per-(tile, digit) nonzero bitmap
-    fetch_ref,  # SMEM (Mt, D) int32 — resident byte group per step (index map)
+    act_ref,  # SMEM (Mt * D,) int32 — per-(tile, digit) nonzero bitmap
+    fetch_ref,  # SMEM (Mt * D,) int32 — resident byte group per step (index map)
     packed_ref,  # (1, bm, K) int8 — byte group fetch[m, d] of the activations
     w_ref,  # (K, bn) f32 — stationary projection weight tile
     scale_ref,  # SMEM (D,) f32 — digit weights (scale-folded), read at d
@@ -185,7 +191,7 @@ def _dslr_matmul_packed_kernel(
     if skip_zero_planes:
         # the SMEM bitmap already knows a dead (tile, digit) — no byte was
         # DMA'd in to find out (cf. the unpacked kernel's jnp.any probe)
-        jax.lax.cond(act_ref[m, d] != 0, _accumulate, lambda: None)
+        jax.lax.cond(act_ref[table_slot(m, d, n_digits)] != 0, _accumulate, lambda: None)
     else:
         _accumulate()
 
@@ -245,21 +251,12 @@ def dslr_matmul_planes_packed(
     if Np != N:
         wf = jnp.pad(wf, ((0, 0), (0, Np - N)))
 
-    if skip_zero_planes:
-        activity = dig.packed_plane_activity(packed, D, bm)  # (Mt, D) int32
-        fetch = plane_fetch_indices(activity, D)
-    else:
-        # no skipping: every digit's own group is resident (fetched once per
-        # 4 digits either way, since consecutive digits share a group)
-        activity = jnp.zeros((Mp // bm, D), jnp.int32)
-        fetch = jnp.broadcast_to(
-            (jnp.arange(D, dtype=jnp.int32) // 4)[None, :], activity.shape
-        )
+    activity, fetch = packed_plane_tables(packed, D, bm, skip_zero_planes)
 
     has_row_scale = row_scale is not None
     has_bias = bias is not None
     in_specs = [
-        pl.BlockSpec((1, bm, K), lambda m, n, d, act, fetch: (fetch[m, d], m, 0)),
+        pl.BlockSpec((1, bm, K), packed_plane_index_map(D)),
         pl.BlockSpec((K, bn), lambda m, n, d, act, fetch: (0, n)),
         SMEM_WHOLE,
     ]

@@ -4,9 +4,10 @@ Pallas's pipelining machinery issues a block copy only when an operand's
 block index *changes* between consecutive grid steps (the grid-revisiting
 rule).  This module replays the exact grid iteration order and index maps of
 ``kernels/dslr_conv2d.py`` — including the packed path's bitmap-driven fetch
-indices, via the very ``plane_fetch_indices`` function the kernel wrapper
-uses — and counts the copies each operand performs.  That makes two of the
-paper's roofline quantities measurable in-repo without a hardware profiler:
+indices, via the very ``plane_tables`` and ``packed_plane_index_map`` the
+kernel wrappers use — and counts the copies each operand performs.  That
+makes two of the paper's roofline quantities measurable in-repo without a
+hardware profiler:
 
   * bytes moved per conv (the Fig. 12 denominator), split per operand, and
   * the structural claims the packed rework makes: the stationary weight
@@ -90,13 +91,13 @@ def packed_dead_group_fetches(
     activity = np.asarray(activity)
     bm, bn, Mp, Np = tuning.conv_tile_dims(M, N, block_m, block_n, interpret)
     grid = (Mp // bm, Np // bn, n_digits)
-    fetch = np.asarray(_dc.plane_fetch_indices(activity, n_digits))
+    fetch = np.asarray(_dc.plane_tables(activity, n_digits)[1])
     G = dig.packed_group_count(n_digits)
     pad = np.zeros((activity.shape[0], 4 * G - n_digits), activity.dtype)
     group_live = np.concatenate([activity, pad], axis=1).reshape(-1, G, 4).any(axis=2)
     dead, last = 0, None
     for m, n, d in np.ndindex(*grid):
-        idx = (int(fetch[m, d]), m, 0)
+        idx = (int(fetch[_dc.table_slot(m, d, n_digits)]), m, 0)
         if idx != last:
             if not group_live[m, idx[0]]:
                 dead += 1
@@ -131,8 +132,9 @@ def conv_planes_traffic(
     if packed and skip_zero_planes:
         if activity is None:
             raise ValueError("packed traffic with skipping needs the activity bitmap")
-        fetch = np.asarray(_dc.plane_fetch_indices(np.asarray(activity), D))
-        patches_map = lambda m, n, d: (fetch[m, d], m, 0)
+        tables = [np.asarray(t) for t in _dc.plane_tables(np.asarray(activity), D)]
+        plane_map = _dc.packed_plane_index_map(D)
+        patches_map = lambda m, n, d: plane_map(m, n, d, *tables)
     elif packed:
         patches_map = lambda m, n, d: (d // 4, m, 0)
     else:

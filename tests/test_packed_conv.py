@@ -112,6 +112,25 @@ def test_packed_fused_epilogue_and_row_scales_bitwise(per_sample, relu):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def test_packed_tables_longer_than_one_smem_row_bitwise():
+    """The flat (Mt * D,) scalar-prefetch tables past one 128-word row: 36
+    row tiles of 8 at 9 digits, 324 slots, with the lower half of each image
+    zero, so dead tiles (every group dead, the fetch clamped to group 0)
+    sit beside live ones."""
+    x, w = rand_conv(29, B=2, H=12, W=12, Cin=3, Cout=5)
+    x = x.at[:, 6:].set(0.0)
+    b = jnp.asarray(np.random.default_rng(4).standard_normal(5), jnp.float32)
+    bm, D = 8, 9
+    _, act, _, M, _ = _packed_patches_and_activity(x, w, 8, 1, bm)
+    assert act.size == (M // bm) * D > 128
+    assert act.any(axis=1).sum() < act.shape[0] - 4  # dead tiles past slot 128 too
+    got = ops.dslr_conv2d_planes(x, w, n_digits=8, padding=1, bias=b, relu=True,
+                                 per_sample=True, block_m=bm, packed=True)
+    want = ref.dslr_conv2d_planes_ref(x, w, n_digits=8, padding=1, bias=b,
+                                      relu=True, per_sample=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_packed_skip_toggle_identical():
     x, w = rand_conv(5)
     a = ops.dslr_conv2d_planes(x, w, padding=1, packed=True, skip_zero_planes=True)

@@ -3,12 +3,12 @@
 Interpret mode never checks Mosaic's tiling rules, so these tests lower and
 compile the kernels with ``interpret=False`` at the widths that are served:
 ResNet-18's first and last conv geometries through the packed conv path
-(per-sample row scales, fused bias and ReLU), the packed conv wrapper at the
-served bucket-8 patch matrices (its dead-plane bitmap copies no digit
-views), the unpacked conv kernel, and the packed matmul at qwen2-0.5b
-decode and prefill widths.  No chip is
-needed — the TPU compiler targets a topology that is described, not
-attached — and nothing runs.
+(per-sample row scales, fused bias and ReLU), VGG-16's two 224x224 convs at
+buckets 4 and 8 the same way, the packed conv wrapper at the served bucket-8
+patch matrices (its dead-plane bitmap copies no digit views), the unpacked
+conv kernel, and the packed matmul at qwen2-0.5b decode and prefill widths.
+No chip is needed — the TPU compiler targets a topology that is described,
+not attached — and nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a test worker that loads it at import
@@ -99,6 +99,50 @@ def test_packed_conv_compiles_for_v5e(one_chip, geom, budget):
     _, x_shape, k, stride, pad, cout = geom
     text = _packed_conv_text(one_chip, x_shape, k, stride, pad, cout, budget)
     assert text.count("tpu_custom_call") >= 1
+
+
+# VGG-16's two 224x224 convs (C1: T = 3*3*3, C2: T = 3*3*64) at the served
+# buckets 4 and 8: M = B*224*224 rows, Mt = 1568 and 3136 row tiles of 128
+VGG16_CONVS = [
+    (f"{name}-B{B}", (B, 224, 224, cin), 3, 1, 1, 64)
+    for name, cin in (("C1", 3), ("C2", 64))
+    for B in (4, 8)
+]
+
+
+@pytest.mark.parametrize("geom", VGG16_CONVS, ids=[g[0] for g in VGG16_CONVS])
+def test_vgg16_packed_conv_compiles_for_v5e(one_chip, geom):
+    """The scalar-prefetch tables of VGG-16's 224x224 convs fit in SMEM: two
+    (Mt, D) tables, each padded to 128 words a row, would need 1.53 MiB of
+    the 1 MiB at bucket 4."""
+    _, x_shape, k, stride, pad, cout = geom
+    text = _packed_conv_text(one_chip, x_shape, k, stride, pad, cout)
+    assert text.count("tpu_custom_call") == 1
+
+
+SMEM_TABLE_BUDGET = 256 * 1024  # bytes for the pair: a quarter of the 1 MiB
+
+
+def _smem_bytes(shape) -> int:
+    """Bytes of an int32 array in SMEM: Mosaic pads the last dimension to 128
+    words."""
+    rows = 1
+    for n in shape[:-1]:
+        rows *= n
+    return rows * -(-shape[-1] // 128) * 128 * 4
+
+
+def test_vgg16_plane_tables_fit_the_smem_budget():
+    """The packed conv's two tables at VGG-16's bucket-8 C1 (Mt = 3136 row
+    tiles of 128, D = 9 digits) take 8 * Mt * D bytes, padding included,
+    where two (Mt, D) tables would take 3.1 MiB."""
+    Mt, D, T = 3136, 9, 27
+    packed = jax.ShapeDtypeStruct((3, Mt * 128, T), jnp.int8)
+    tables = jax.eval_shape(lambda p: dc.packed_plane_tables(p, D, 128, True), packed)
+    assert [t.shape for t in tables] == [(Mt * D,)] * 2
+    used = sum(_smem_bytes(t.shape) for t in tables)
+    assert used <= SMEM_TABLE_BUDGET, used
+    assert 2 * _smem_bytes((Mt, D)) > 1024 * 1024
 
 
 # (name, input (B, H, W, Cin), kernel, stride, padding): the served bucket-8
