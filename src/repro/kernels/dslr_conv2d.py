@@ -66,11 +66,73 @@ from . import tuning
 # multiple of 8 or the whole array's.
 SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-# Planes are exact in any float format, but the weights are f32: at XLA's
-# default TPU precision the MXU would round them to bf16.  HIGHEST keeps every
-# plane x weight product exact, the premise of the bounds in docs/NUMERICS.md;
-# kernels/ref.py names the same precision.
-DOT_PRECISION = jax.lax.Precision.HIGHEST
+# Every digit-plane dot runs as three bf16 MXU passes.  A plane holds only -1,
+# 0 and +1, exact in bf16, and an f32 weight is exactly the sum of three bf16
+# limbs, so every plane x limb product is exact and the f32 accumulation sees
+# the same values as an f32 x f32 dot at Precision.HIGHEST, whose six passes
+# would spend three on the plane's zero mid and lo limbs.  The split is exact
+# for |w| >= about 2**-102; below that the TPU flushes the lo limb's
+# denormals, as HIGHEST's own split does.  kernels/ref.py shares both helpers,
+# which keeps the oracle bitwise-coupled to the kernels.
+_BF16_HEAD = -(1 << 16)  # int32 0xFFFF0000: sign, exponent, 7 fraction bits
+
+
+def _bf16_head(x: jax.Array) -> jax.Array:
+    """f32 ``x`` with the low 16 bits of its pattern cleared: a bf16 value
+    held in f32, so the cast to bf16 that follows is exact.  Truncating by
+    bits leaves no f32 -> bf16 -> f32 round trip for a compiler to fold."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jax.lax.bitcast_convert_type(bits & _BF16_HEAD, jnp.float32)
+
+
+def weight_limbs(w: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The three bf16 limbs (hi, mid, lo) of f32 ``w``, each of the shape of
+    ``w``: ``f32(hi) + f32(mid) + f32(lo) == w`` exactly.  hi keeps w's top 8
+    significand bits, mid the next 8, lo the last 8; each subtraction is
+    exact (the subtrahend is a truncation of the minuend)."""
+    w = w.astype(jnp.float32)
+    hi = _bf16_head(w)
+    rest = w - hi
+    mid = _bf16_head(rest)
+    lo = rest - mid
+    return tuple(limb.astype(jnp.bfloat16) for limb in (hi, mid, lo))
+
+
+def plane_dot(plane: jax.Array, limbs) -> jax.Array:
+    """``plane @ w`` in f32 as three bf16 MXU passes over ``w``'s limbs.
+
+    ``plane``: (..., T) digits in {-1, 0, +1} of any integer or float dtype;
+    ``limbs``: the three (T, N) bf16 limbs of ``weight_limbs``, as a tuple or
+    a (3, T, N) ref.  The kernels and the oracle add the passes in this one
+    order, ``(hi + mid) + lo``."""
+    p = plane.astype(jnp.bfloat16)
+    dims = (((p.ndim - 1,), (0,)), ((), ()))
+
+    def one_pass(i):
+        return jax.lax.dot_general(p, limbs[i], dims, preferred_element_type=jnp.float32)
+
+    return (one_pass(0) + one_pass(1)) + one_pass(2)
+
+
+def unpack_digit(packed: jax.Array, d) -> jax.Array:
+    """Digit ``d`` of a packed int8 block as int32 in {-1, 0, +1}: shift and
+    mask its 2-bit field on the VPU, then the sign extension pack_planes
+    inverts — bit-for-bit the unpacked kernel's plane."""
+    v = (packed.astype(jnp.int32) >> (2 * (d % 4))) & 3
+    return v - ((v & 2) << 1)
+
+
+def plane_dot_scratch(bm: int, bn: int, T: int) -> list:
+    """VMEM scratch of a digit-plane kernel: the (bm, bn) f32 accumulator and
+    the stationary weight tile's (3, T, bn) bf16 limbs."""
+    return [pltpu.VMEM((bm, bn), jnp.float32), pltpu.VMEM((3, T, bn), jnp.bfloat16)]
+
+
+def store_weight_limbs(w_ref, limbs_ref):
+    """Write the stationary weight tile's limbs into the (3, T, bn) bf16
+    scratch, once per (m, n) output tile."""
+    for i, limb in enumerate(weight_limbs(w_ref[...])):
+        limbs_ref[i] = limb
 
 
 def _epilogue(acc, bias_ref, apply_relu: bool):
@@ -119,7 +181,7 @@ def _dslr_conv2d_kernel(
     w_ref,  # (T, bn) f32 — stationary flattened filter tile
     scale_ref,  # SMEM (D,) f32 — digit weights, plane d reads scale_ref[d]
     *refs,  # [row_scale_ref (bm, 1) if has_row_scale,] [bias_ref (1, bn) if
-    #        has_bias,] out_ref (bm, bn), acc_ref scratch
+    #        has_bias,] out_ref (bm, bn), acc_ref and limbs_ref scratch
     n_digits: int,
     skip_zero_planes: bool,
     has_row_scale: bool,
@@ -128,12 +190,13 @@ def _dslr_conv2d_kernel(
 ):
     row_scale_ref = refs[0] if has_row_scale else None
     bias_ref = refs[1] if (has_row_scale and has_bias) else refs[0] if has_bias else None
-    out_ref, acc_ref = refs[-2], refs[-1]
+    out_ref, acc_ref, limbs_ref = refs[-3], refs[-2], refs[-1]
     d = pl.program_id(2)
 
     @pl.when(d == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        store_weight_limbs(w_ref, limbs_ref)
 
     plane = planes_ref[0]
     # the activation quantization scale reaches the accumulator inside the
@@ -147,14 +210,7 @@ def _dslr_conv2d_kernel(
         scale = scale * row_scale_ref[...]
 
     def _accumulate():
-        contrib = jax.lax.dot_general(
-            plane.astype(jnp.float32),
-            w_ref[...],
-            (((1,), (0,)), ((), ())),
-            precision=DOT_PRECISION,
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] += scale * contrib
+        acc_ref[...] += scale * plane_dot(plane, limbs_ref)
 
     if skip_zero_planes:
         # widened first: Mosaic cannot relayout an int8-shaped i1 mask
@@ -240,7 +296,7 @@ def dslr_conv2d_planes_mxu(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, d: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=plane_dot_scratch(bm, bn, T),
         interpret=interpret,
     )(*operands)
     return out[:M, :N]
@@ -329,7 +385,8 @@ def _dslr_conv2d_packed_kernel(
     packed_ref,  # (1, bm, T) int8 — byte group fetch[m, d] of the patches
     w_ref,  # (T, bn) f32 — stationary flattened filter tile
     scale_ref,  # SMEM (D,) f32 — digit weights, plane d reads scale_ref[d]
-    *refs,  # [row_scale_ref,] [bias_ref,] [inv_ref if emit,] out_ref, acc_ref
+    *refs,  # [row_scale_ref,] [bias_ref,] [inv_ref if emit,] out_ref, acc_ref,
+    #        limbs_ref
     n_digits: int,
     skip_zero_planes: bool,
     has_row_scale: bool,
@@ -340,32 +397,21 @@ def _dslr_conv2d_packed_kernel(
     del fetch_ref  # consumed by the index map, not the body
     row_scale_ref = refs[0] if has_row_scale else None
     bias_ref = refs[1] if (has_row_scale and has_bias) else refs[0] if has_bias else None
-    inv_ref = refs[-3] if emit is not None else None
-    out_ref, acc_ref = refs[-2], refs[-1]
+    inv_ref = refs[-4] if emit is not None else None
+    out_ref, acc_ref, limbs_ref = refs[-3], refs[-2], refs[-1]
     m, d = pl.program_id(0), pl.program_id(2)
 
     @pl.when(d == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        store_weight_limbs(w_ref, limbs_ref)
 
     scale = scale_ref[d]
     if has_row_scale:
         scale = scale * row_scale_ref[...]
 
     def _accumulate():
-        # widen digit d from its 2-bit field: shift/mask on the VPU, then the
-        # same 2-bit sign extension pack_planes inverts — the resulting f32
-        # plane is bit-for-bit the unpacked kernel's operand
-        v = (packed_ref[0].astype(jnp.int32) >> (2 * (d % 4))) & 3
-        plane = (v - ((v & 2) << 1)).astype(jnp.float32)
-        contrib = jax.lax.dot_general(
-            plane,
-            w_ref[...],
-            (((1,), (0,)), ((), ())),
-            precision=DOT_PRECISION,
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] += scale * contrib
+        acc_ref[...] += scale * plane_dot(unpack_digit(packed_ref[0], d), limbs_ref)
 
     if skip_zero_planes:
         # the SMEM bitmap already knows a dead (tile, digit) — no byte was
@@ -504,7 +550,7 @@ def dslr_conv2d_planes_packed_mxu(
         grid=(Mp // bm, Np // bn, D),
         in_specs=in_specs,
         out_specs=out_spec,
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=plane_dot_scratch(bm, bn, T),
     )
     out = pl.pallas_call(
         functools.partial(

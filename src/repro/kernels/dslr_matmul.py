@@ -24,8 +24,9 @@ tile is zeroed at d == 0 and flushed to HBM at d == D-1.  The contraction
 
 BlockSpec tiling (v5e): MXU is 128x128; default tiles are (128, K) x (K, 128)
 with VMEM footprint  128*K (int8 plane) + K*128*4 (f32 weights) +
-2 * 128*128*4 (acc + out)  =  K*640 B + 128 KiB  — under the ~16 MiB VMEM
-budget for K up to ~24k, i.e. every assigned architecture's d_model/d_ff.
+K*128*6 (the weights' three bf16 limbs) + 2 * 128*128*4 (acc + out)  =
+K*1408 B + 128 KiB, before double buffering — qwen2-0.5b's d_ff of 4864
+and VGG-16's T = 4608 compile for a v5e (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -40,11 +41,14 @@ from repro.core import digits as dig
 
 from . import tuning
 from .dslr_conv2d import (
-    DOT_PRECISION,
     SMEM_WHOLE,
     packed_plane_index_map,
     packed_plane_tables,
+    plane_dot,
+    plane_dot_scratch,
+    store_weight_limbs,
     table_slot,
+    unpack_digit,
 )
 
 
@@ -54,6 +58,7 @@ def _dslr_matmul_kernel(
     scale_ref,  # SMEM (D,) f32 — digit weights, plane d reads scale_ref[d]
     out_ref,  # (bm, bn) f32
     acc_ref,  # VMEM scratch (bm, bn) f32
+    limbs_ref,  # VMEM scratch (3, K, bn) bf16 — the weight tile's limbs
     *,
     n_digits: int,
     skip_zero_planes: bool,
@@ -63,19 +68,13 @@ def _dslr_matmul_kernel(
     @pl.when(d == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        store_weight_limbs(w_ref, limbs_ref)
 
     plane = planes_ref[0]
     scale = scale_ref[d]
 
     def _accumulate():
-        contrib = jax.lax.dot_general(
-            plane.astype(jnp.float32),
-            w_ref[...],
-            (((1,), (0,)), ((), ())),
-            precision=DOT_PRECISION,
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] += scale * contrib
+        acc_ref[...] += scale * plane_dot(plane, limbs_ref)
 
     if skip_zero_planes:
         # CSD leaves ~2/3 of digits zero — skip the MXU pass for all-zero
@@ -129,7 +128,7 @@ def dslr_matmul_planes(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, d: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=plane_dot_scratch(bm, bn, K),
         interpret=interpret,
     )(planes, w.astype(jnp.float32), digit_scales.astype(jnp.float32))
 
@@ -149,7 +148,8 @@ def _dslr_matmul_packed_kernel(
     packed_ref,  # (1, bm, K) int8 — byte group fetch[m, d] of the activations
     w_ref,  # (K, bn) f32 — stationary projection weight tile
     scale_ref,  # SMEM (D,) f32 — digit weights (scale-folded), read at d
-    *refs,  # [row_scale_ref (bm, 1),] [bias_ref (1, bn),] out_ref, acc_ref
+    *refs,  # [row_scale_ref (bm, 1),] [bias_ref (1, bn),] out_ref, acc_ref,
+    #        limbs_ref
     n_digits: int,
     skip_zero_planes: bool,
     has_row_scale: bool,
@@ -158,12 +158,13 @@ def _dslr_matmul_packed_kernel(
     del fetch_ref  # consumed by the index map, not the body
     row_scale_ref = refs[0] if has_row_scale else None
     bias_ref = refs[1] if (has_row_scale and has_bias) else refs[0] if has_bias else None
-    out_ref, acc_ref = refs[-2], refs[-1]
+    out_ref, acc_ref, limbs_ref = refs[-3], refs[-2], refs[-1]
     m, d = pl.program_id(0), pl.program_id(2)
 
     @pl.when(d == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        store_weight_limbs(w_ref, limbs_ref)
 
     # the activation quantization scale reaches the accumulator inside the
     # per-plane step — folded into ``digit_scales`` (per-tensor) or via
@@ -174,19 +175,7 @@ def _dslr_matmul_packed_kernel(
         scale = scale * row_scale_ref[...]
 
     def _accumulate():
-        # widen digit d from its 2-bit field: shift/mask on the VPU, then the
-        # same 2-bit sign extension pack_planes inverts — the resulting f32
-        # plane is bit-for-bit the unpacked kernel's operand
-        v = (packed_ref[0].astype(jnp.int32) >> (2 * (d % 4))) & 3
-        plane = (v - ((v & 2) << 1)).astype(jnp.float32)
-        contrib = jax.lax.dot_general(
-            plane,
-            w_ref[...],
-            (((1,), (0,)), ((), ())),
-            precision=DOT_PRECISION,
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] += scale * contrib
+        acc_ref[...] += scale * plane_dot(unpack_digit(packed_ref[0], d), limbs_ref)
 
     if skip_zero_planes:
         # the SMEM bitmap already knows a dead (tile, digit) — no byte was
@@ -279,7 +268,7 @@ def dslr_matmul_planes_packed(
         grid=(Mp // bm, Np // bn, D),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, d, act, fetch: (m, n)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=plane_dot_scratch(bm, bn, K),
     )
     out = pl.pallas_call(
         functools.partial(

@@ -1,12 +1,14 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose targets).
 
-Every matmul names the kernels' dot precision (``DOT_PRECISION``): on a TPU
-the default f32 precision rounds operands to bf16, and oracle and kernel
-would then disagree by far more than summation order.
+Every digit-plane dot goes through the kernels' own ``plane_dot`` over the
+weights' three exact bf16 limbs (``weight_limbs``): each product is exactly
++-w or 0, as the plane is exact in bf16 and w is exactly its three limbs,
+and the passes add in the kernels' order.  The two f32 x f32 products that
+are not plane dots (the digit-scale contraction and the sLSTM recurrence)
+name ``Precision.HIGHEST``: on a TPU the default f32 precision rounds
+operands to bf16.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +16,10 @@ import jax.numpy as jnp
 from repro.core import digits as dig
 from repro.core import dslr as core_dslr
 
-from .dslr_conv2d import DOT_PRECISION
+from .dslr_conv2d import plane_dot, weight_limbs
 
-_matmul = functools.partial(jnp.matmul, precision=DOT_PRECISION)
-_einsum = functools.partial(jnp.einsum, precision=DOT_PRECISION)
+# for the f32 x f32 products that are not digit-plane dots
+F32_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def dslr_conv2d_planes_ref(
@@ -61,6 +63,7 @@ def dslr_conv2d_planes_ref(
     D, _, Ho, Wo, T = patches.shape
     planes = patches.reshape(D, B * Ho * Wo, T)
     w_flat = core_dslr.flatten_conv_weights(w).astype(jnp.float32)
+    limbs = weight_limbs(w_flat)
     fused = bias is not None or relu
     scales = core_dslr.digit_scales(D)
     if fused and not per_sample:
@@ -76,7 +79,7 @@ def dslr_conv2d_planes_ref(
         s, plane = jp
         if row_scale is not None:
             s = s * row_scale
-        return acc + s * _matmul(plane.astype(jnp.float32), w_flat), None
+        return acc + s * plane_dot(plane, limbs), None
 
     zeros = jnp.zeros((B * Ho * Wo, w_flat.shape[1]), jnp.float32)
     acc, _ = jax.lax.scan(body, zeros, (scales, planes))
@@ -112,16 +115,16 @@ def planes_scan_flat_ref(
     accumulator (the wrapper reshapes and, when unfused, scales)."""
     if packed:
         planes = dig.unpack_planes(planes, n_planes)
-    w32 = w_flat.astype(jnp.float32)
+    limbs = weight_limbs(w_flat)
     rs = None if row_scale is None else row_scale.astype(jnp.float32)[:, None]
 
     def body(acc, jp):
         s, plane = jp
         if rs is not None:
             s = s * rs
-        return acc + s * _matmul(plane.astype(jnp.float32), w32), None
+        return acc + s * plane_dot(plane, limbs), None
 
-    zeros = jnp.zeros((planes.shape[1], w32.shape[1]), jnp.float32)
+    zeros = jnp.zeros((planes.shape[1], w_flat.shape[1]), jnp.float32)
     acc, _ = jax.lax.scan(
         body, zeros, (digit_scales.astype(jnp.float32), planes)
     )
@@ -136,11 +139,9 @@ def dslr_matmul_planes_ref(
     planes: jax.Array, w: jax.Array, digit_scales: jax.Array
 ) -> jax.Array:
     """sum_d scales[d] * (planes[d] @ w) — dense, no skipping."""
-    contribs = _einsum(
-        "dmk,kn->dmn", planes.astype(jnp.float32), w.astype(jnp.float32)
-    )
+    contribs = plane_dot(planes, weight_limbs(w))
     return jnp.tensordot(
-        digit_scales.astype(jnp.float32), contribs, axes=1, precision=DOT_PRECISION
+        digit_scales.astype(jnp.float32), contribs, axes=1, precision=F32_PRECISION
     )
 
 
@@ -174,13 +175,13 @@ def dslr_matmul_packed_ref(
         row_scale = q.scale.astype(jnp.float32)[:, None]
     else:
         scales = q.scale * scales
-    wf = w.astype(jnp.float32)
+    limbs = weight_limbs(w)
 
     def body(acc, jp):
         s, plane = jp
         if row_scale is not None:
             s = s * row_scale
-        return acc + s * _matmul(plane.astype(jnp.float32), wf), None
+        return acc + s * plane_dot(plane, limbs), None
 
     zeros = jnp.zeros((x.shape[0], w.shape[1]), jnp.float32)
     acc, _ = jax.lax.scan(body, zeros, (scales, planes))
@@ -223,8 +224,11 @@ def slstm_sweep_ref(wx: jax.Array, r_w: jax.Array, n_heads: int):
 
     def step(state, g_in):
         c, n, h, m = state
-        rec = _einsum(
-            "bhd,hde->bhe", h.reshape(B, n_heads, Dh), r_w.astype(jnp.float32)
+        rec = jnp.einsum(
+            "bhd,hde->bhe",
+            h.reshape(B, n_heads, Dh),
+            r_w.astype(jnp.float32),
+            precision=F32_PRECISION,
         ).reshape(B, 4 * d)
         g = g_in.astype(jnp.float32) + rec
         gi, gf, gz, go = jnp.split(g, 4, axis=-1)

@@ -4,9 +4,11 @@ Interpret mode never checks Mosaic's tiling rules, so these tests lower and
 compile the kernels with ``interpret=False`` at the widths that are served:
 ResNet-18's first and last conv geometries through the packed conv path
 (per-sample row scales, fused bias and ReLU), VGG-16's two 224x224 convs at
-buckets 4 and 8 the same way, the packed conv wrapper at the served bucket-8
-patch matrices (its dead-plane bitmap copies no digit views), the unpacked
-conv kernel, and the packed matmul at qwen2-0.5b decode and prefill widths.
+buckets 4 and 8 the same way, the kernel's VMEM peak (a T = 4608 conv at
+full row tiles) and the 1x1 projections, the packed conv wrapper at the
+served bucket-8 patch matrices (its dead-plane bitmap copies no digit
+views), the unpacked conv kernel, and the packed matmul at qwen2-0.5b decode
+and prefill widths.
 No chip is needed — the TPU compiler targets a topology that is described,
 not attached — and nothing runs.
 
@@ -115,6 +117,25 @@ def test_vgg16_packed_conv_compiles_for_v5e(one_chip, geom):
     """The scalar-prefetch tables of VGG-16's 224x224 convs fit in SMEM: two
     (Mt, D) tables, each padded to 128 words a row, would need 1.53 MiB of
     the 1 MiB at bucket 4."""
+    _, x_shape, k, stride, pad, cout = geom
+    text = _packed_conv_text(one_chip, x_shape, k, stride, pad, cout)
+    assert text.count("tpu_custom_call") == 1
+
+
+# The digit-plane dot's three bf16 passes against the (3, T, bn) bf16 limbs
+# of the weight tile: VGG-16's C11 at bucket 8 (T = 3*3*512 = 4608 at full
+# 128-row tiles, M = 8*14*14), the kernel's VMEM peak, and ResNet-18's 1x1
+# stride-2 projections (T = 64, 128 and 256) at bucket 8
+PLANE_DOT_CONVS = [
+    ("vgg16-C11-B8", (8, 14, 14, 512), 3, 1, 1, 512),
+    ("ds-T64-B8", (8, 56, 56, 64), 1, 2, 0, 128),
+    ("ds-T128-B8", (8, 28, 28, 128), 1, 2, 0, 256),
+    ("ds-T256-B8", (8, 14, 14, 256), 1, 2, 0, 512),
+]
+
+
+@pytest.mark.parametrize("geom", PLANE_DOT_CONVS, ids=[g[0] for g in PLANE_DOT_CONVS])
+def test_plane_dot_geometries_compile_for_v5e(one_chip, geom):
     _, x_shape, k, stride, pad, cout = geom
     text = _packed_conv_text(one_chip, x_shape, k, stride, pad, cout)
     assert text.count("tpu_custom_call") == 1
